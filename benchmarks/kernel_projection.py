@@ -7,7 +7,7 @@ script MEASURES the HLO-modeled per-device attention traffic by lowering an
 isolated per-device-shaped attention fwd+bwd and running the same
 trip-count-aware analyzer, then substitutes the kernel-boundary bytes:
 
-  adjusted_mem = mem - n_calls * (T_hlo_attn - T_kernel_attn) / HBM_BW
+  adjusted_mem = mem - n_calls * (T_hlo_attn - T_kernel_attn) / HBM bandwidth
 
 Reported per hillclimb cell as the 'pallas' projection (EXPERIMENTS.md
 §Perf).  The kernel itself is validated vs its oracle in tests/.
@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.launch import hlo_analysis
-from repro.launch.roofline import HBM_BW
+from repro.launch.roofline import peak_for
 from repro.models.attention import attend_chunked
 
 
@@ -67,7 +67,9 @@ def project_cell(cell: dict, *, b_loc, h_loc, s, d, kv_loc, layers,
     kern_total = layers * (k_f + k_fb)
     saved = hlo_total - kern_total
     adj = dict(cell)
-    adj["memory_s"] = cell["memory_s"] - saved / HBM_BW
+    # the dry-run cells it adjusts describe TPU v5e chips
+    hbm_bw = peak_for("TPU v5 lite").hbm_bytes_per_s
+    adj["memory_s"] = cell["memory_s"] - saved / hbm_bw
     adj["per_device_bytes"] = cell["per_device_bytes"] - saved
     adj["attn_hlo_bytes"] = hlo_total
     adj["attn_kernel_bytes"] = kern_total

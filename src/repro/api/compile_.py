@@ -44,6 +44,7 @@ import dataclasses
 import time
 from typing import Callable, Optional
 
+import jax
 import numpy as np
 
 from repro.api.program import Program
@@ -92,6 +93,20 @@ def _resolve_devices(devices, policy) -> dict:
         "because a dispatcher's tuning cache IS the device identity")
 
 
+def _device_transfer(dispatchers: dict) -> Optional[Callable]:
+    """The move hook between dispatchers bound to ``jax.Device``s, or None
+    when none is bound."""
+    chips = {name: getattr(d, "device", None)
+             for name, d in dispatchers.items()}
+    if not any(chips.values()):
+        return None
+
+    def move(value, tr: Transfer):
+        chip = chips[tr.dst]
+        return value if chip is None else jax.device_put(value, chip)
+    return move
+
+
 def compile_program(program: Program, devices=None, policy=None,
                     bindings=None, executor: str = "sequential",
                     comm=None, transfer=None, topology=None,
@@ -101,7 +116,10 @@ def compile_program(program: Program, devices=None, policy=None,
     ``(src, dst, nbytes) -> seconds`` callable) that makes the EFT
     schedule transfer-aware; ``transfer`` is the physical move hook
     ``(value, Transfer) -> value`` the async path applies per materialized
-    transfer (None: same-host devices share memory, the move is free).
+    transfer.  When it is None and the dispatchers are bound to
+    ``jax.Device``s (``Dispatcher(device=)``), a move is a
+    ``jax.device_put`` to the destination's chip; with no bound device the
+    devices share memory and the move is free.
 
     ``topology`` is a ``repro.exec.Topology``: transfers then queue on
     shared-bus lanes in both the EFT schedule and the executor (a bus with
@@ -132,6 +150,8 @@ def compile_program(program: Program, devices=None, policy=None,
         if hasattr(comm, "comm_fn") and \
                 getattr(comm, "telemetry", None) is None:
             comm.telemetry = telemetry
+    if transfer is None:
+        transfer = _device_transfer(dispatchers)
     tasks = program.to_kernel_tasks()
     predict = predictor_from_runtime(dispatchers)
     comm_fn = comm.comm_fn() if hasattr(comm, "comm_fn") else comm

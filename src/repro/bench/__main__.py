@@ -20,6 +20,7 @@ from repro.bench.harness import (DEFAULT_CONFIGS, run_adaptive, run_bench,
 from repro.bench.history import (DEFAULT_PATTERNS, discover, format_history,
                                  load_row)
 from repro.bench.schema import load_bench, validate_bench
+from repro.compile_cache import enable_compile_cache
 from repro.workloads import SIZES
 
 
@@ -95,6 +96,8 @@ def main(argv=None) -> int:
                            "on sim, warns on real)")
 
     args = ap.parse_args(argv)
+    if args.cmd in ("run", "adaptive", "serve"):
+        enable_compile_cache()
     if args.cmd == "run":
         doc = run_bench(
             quick=args.quick, out_path=args.out,

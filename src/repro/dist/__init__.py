@@ -24,9 +24,9 @@ dimension sizes all resolve to "replicated" rather than erroring, so rule
 sets can be written for the production mesh and still work on small test
 meshes.
 
-:mod:`repro.dist.compat` wraps the mesh/shard_map API differences across
-jax versions; all mesh construction and shard_map entry in ``repro`` goes
-through it.
+:mod:`repro.dist.compat` holds the one spelling of mesh construction and
+shard_map (Auto axis types, no replication check); all mesh construction
+and shard_map entry in ``repro`` goes through it.
 """
 from repro.dist.sharding import (ShardingRules, active_mesh, active_rules,
                                  batch_shardings, constrain, serve_rules,

@@ -1,44 +1,25 @@
-"""Version-tolerant wrappers over the jax mesh / shard_map APIs.
+"""The one spelling of mesh construction and shard_map the repo uses.
 
-The distributed layer targets the modern explicit-sharding API surface
-(``jax.make_mesh(..., axis_types=...)``, ``jax.shard_map(..., check_vma=...)``)
-but must also run on older jaxlib builds where those spellings do not exist
-(``AxisType`` absent, ``shard_map`` still under ``jax.experimental`` with a
-``check_rep`` flag).  Everything in ``repro`` that builds a mesh or enters a
-shard_map region goes through these two functions.
+Everything in ``repro`` that builds a mesh or enters a shard_map region
+goes through these two functions, so the axis types and the replication
+check are decided in one place.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(AxisType.Auto,) * len(axis_names),
-                             devices=devices)
-    except (ImportError, TypeError):
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def shard_map(f, mesh, *, in_specs, out_specs):
-    """``jax.shard_map`` without replication checking, on any jax version.
-
-    Replication checking is disabled in all spellings (``check_vma=False`` /
-    ``check_rep=False``): the MoE and ring-attention bodies compute routing
-    redundantly per rank, which the checker cannot verify.
-    """
-    if hasattr(jax, "shard_map"):
-        # newest spelling first, then the mid-range one; never a bare call —
-        # that would silently re-enable checking and break far from here
-        for kwargs in ({"check_vma": False}, {"check_rep": False}):
-            try:
-                return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, **kwargs)
-            except TypeError:
-                continue
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` without replication checking: the MoE and
+    ring-attention bodies compute routing redundantly per rank, which the
+    checker cannot verify."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

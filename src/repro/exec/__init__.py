@@ -14,6 +14,7 @@ front door; the sequential bridge stays as the bit-exact reference.
 from repro.exec.buffers import (BufferTable, Transfer, plan_buffers,
                                 value_nbytes)
 from repro.exec.comm import (DEFAULT_SIZES, TRANSFER_FEATURES, Bus,
-                             CommModel, Topology, transfer_kernel)
+                             CommModel, Topology, device_pair_transfer,
+                             transfer_kernel)
 from repro.exec.executor import AsyncExecutor, ExecTask, StealPolicy
 from repro.exec.trace import ExecutionTrace, TraceEvent

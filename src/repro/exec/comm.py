@@ -100,6 +100,22 @@ class Topology:
                     for i, a in enumerate(devs) for b in devs[i + 1:]])
 
 
+def device_pair_transfer(src, dst) -> Callable[[np.ndarray], object]:
+    """``measure_pair``'s ``transfer_fn`` between two ``jax.Device``s: the
+    payload is placed on ``src`` once, in the protocol's warmup call, and
+    every timed call copies it to ``dst`` and waits for the copy."""
+    import jax
+
+    placed: dict = {}
+
+    def move(buf: np.ndarray):
+        x = placed.get(buf.nbytes)
+        if x is None:
+            x = placed[buf.nbytes] = jax.device_put(buf, src)
+        return jax.block_until_ready(jax.device_put(x, dst))
+    return move
+
+
 def transfer_kernel(src: str, dst: str) -> str:
     """Cache entry name of the (src, dst) pseudo-kernel (doubles as its
     on-disk file stem, hence no path-hostile characters)."""

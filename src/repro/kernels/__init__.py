@@ -1,12 +1,10 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
 """Shared kernel-dispatch policy helpers + the abstract-value contract."""
 from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
 import jax
+import numpy as np
 
 
 class Aval(NamedTuple):
@@ -17,23 +15,50 @@ class Aval(NamedTuple):
     shape: tuple
     dtype: object
 
-# backends whose Pallas lowering is compiled, not interpreted
-_COMPILED_BACKENDS = ("gpu", "cuda", "rocm", "tpu")
-
 
 def default_interpret(backend: Optional[str] = None) -> bool:
-    """Whether Pallas kernels should default to interpret mode.
-
-    On CPU (this container, most CI) there is no Pallas lowering, so kernels
-    must run interpreted; on GPU/TPU the compiled path is the whole point.
-    Every ``ops.py`` entry point takes ``interpret=None`` and resolves it
-    here, so callers only ever override deliberately (e.g. debugging a
-    miscompile with ``interpret=True`` on an accelerator).
-    """
-    backend = backend or jax.default_backend()
-    return backend not in _COMPILED_BACKENDS
+    """Whether Pallas kernels run in interpret mode: only on the CPU, which
+    has no Pallas lowering.  Every accelerator runs the compiled kernel."""
+    return (backend or jax.default_backend()) == "cpu"
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """``None`` -> backend-derived default; explicit bools pass through."""
-    return default_interpret() if interpret is None else bool(interpret)
+    """``None`` -> backend-derived default.  An explicit ``True`` on a TPU
+    is refused: an interpreted kernel there would hide the device."""
+    if interpret is None:
+        return default_interpret()
+    if interpret and jax.default_backend() == "tpu":
+        raise ValueError("interpret=True on a TPU: Pallas kernels run "
+                         "compiled on the chip")
+    return bool(interpret)
+
+
+# The stencil kernels (blur, conv2d, maxpool) hold their whole input in one
+# VMEM block.  On a TPU v5e the compiler accepts such a block up to about
+# 16 MiB of tiled footprint (rows padded to the sublane tile, columns to 128
+# lanes); this bound keeps a margin for the output tiles.  Inputs within it
+# compile for the chip (tests/test_tpu_compile.py); larger ones need a
+# halo'd, tiled DMA schedule the kernels do not have yet.
+RESIDENT_INPUT_MAX_BYTES = 15 * 2 ** 20
+
+
+def vmem_footprint(shape, dtype) -> int:
+    """Bytes a block of ``shape`` occupies in VMEM under the (8, 128)
+    32-bit tiling (narrower dtypes pack more rows per sublane tile)."""
+    itemsize = np.dtype(dtype).itemsize
+    sub = 8 * max(1, 4 // itemsize)
+    *lead, rows, cols = shape
+    return int(np.prod(lead, dtype=np.int64)) * (-(-rows // sub) * sub) \
+        * (-(-cols // 128) * 128) * itemsize
+
+
+def check_resident_input(kernel: str, shape, dtype) -> None:
+    """Raise for a stencil input whose VMEM-resident block cannot fit."""
+    nbytes = vmem_footprint(shape, dtype)
+    if nbytes > RESIDENT_INPUT_MAX_BYTES:
+        raise ValueError(
+            f"{kernel}: input block {tuple(shape)} {np.dtype(dtype).name} "
+            f"occupies {nbytes} bytes of VMEM; the Pallas kernel keeps its "
+            f"whole padded input there and takes at most "
+            f"{RESIDENT_INPUT_MAX_BYTES} — use the reference path "
+            "(use_kernel=False) for larger inputs")

@@ -21,8 +21,8 @@ from jax.experimental import pallas as pl
 def _blur_direct_kernel(bm, bn, a_ref, o_ref):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    tile = pl.load(a_ref, (pl.dslice(i * bm, bm + 2),
-                           pl.dslice(j * bn, bn + 2))).astype(jnp.float32)
+    tile = a_ref[pl.ds(i * bm, bm + 2),
+                 pl.ds(j * bn, bn + 2)].astype(jnp.float32)
     acc = jnp.zeros((bm, bn), jnp.float32)
     for di in range(3):
         for dj in range(3):
@@ -33,8 +33,8 @@ def _blur_direct_kernel(bm, bn, a_ref, o_ref):
 def _blur_h_kernel(bm, bn, a_ref, o_ref):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    tile = pl.load(a_ref, (pl.dslice(i * bm, bm),
-                           pl.dslice(j * bn, bn + 2))).astype(jnp.float32)
+    tile = a_ref[pl.ds(i * bm, bm),
+                 pl.ds(j * bn, bn + 2)].astype(jnp.float32)
     acc = tile[:, 0:bn] + tile[:, 1:bn + 1] + tile[:, 2:bn + 2]
     o_ref[...] = (acc * (1.0 / 3.0)).astype(o_ref.dtype)
 
@@ -42,8 +42,8 @@ def _blur_h_kernel(bm, bn, a_ref, o_ref):
 def _blur_v_kernel(bm, bn, a_ref, o_ref):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    tile = pl.load(a_ref, (pl.dslice(i * bm, bm + 2),
-                           pl.dslice(j * bn, bn))).astype(jnp.float32)
+    tile = a_ref[pl.ds(i * bm, bm + 2),
+                 pl.ds(j * bn, bn)].astype(jnp.float32)
     acc = tile[0:bm] + tile[1:bm + 1] + tile[2:bm + 2]
     o_ref[...] = (acc * (1.0 / 3.0)).astype(o_ref.dtype)
 
@@ -62,7 +62,7 @@ def _pallas_2d(kernel, in_arr, out_shape, grid, bm, bn, interpret):
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "separable", "interpret"))
 def blur(a: jax.Array, *, bm: int = 128, bn: int = 128,
-         separable: bool = False, interpret: bool = True) -> jax.Array:
+         separable: bool = False, interpret: bool) -> jax.Array:
     """a: [om+2, on+2] with om % bm == 0 and on % bn == 0 -> [om, on]."""
     m, n = a.shape
     om, on = m - 2, n - 2

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import Aval, resolve_interpret
+from repro.kernels import Aval, check_resident_input, resolve_interpret
 from repro.kernels.blur import blur as _kernel
 from repro.kernels.blur import ref as _ref
 
@@ -39,6 +39,9 @@ def blur(a: jax.Array, *, bm: int = 128, bn: int = 128,
     om, on = m - 2, n - 2
     pm, pn = (-om) % bm, (-on) % bn
     ap = jnp.pad(a, ((0, pm), (0, pn))) if (pm or pn) else a
+    # the separable schedule's first pass reads rows padded to a bm multiple
+    rows = ap.shape[0] + ((-ap.shape[0]) % bm if separable else 0)
+    check_resident_input("blur", (rows, ap.shape[1]), ap.dtype)
     out = _kernel.blur(ap, bm=bm, bn=bn, separable=separable,
                        interpret=interpret)
     return out[:om, :on]

@@ -1,11 +1,12 @@
 """Valid 2-D convolution (cross-correlation) Pallas kernel.
 
 Output is tiled on a (m/bm, n/bn) grid; the input stays VMEM-resident and
-each tile loads its halo'd window with ``pl.dslice`` (overlapping windows
-are not expressible as strided BlockSpecs).  The r x r taps unroll into
+each tile reads its halo'd window by ``pl.ds`` ref indexing (overlapping
+windows are not expressible as strided BlockSpecs).  The r x r taps unroll into
 shift-multiply-accumulate over the tile — VPU-friendly, no gathers.  For
 inputs beyond VMEM a production schedule would add halo'd double-buffered
-DMA; the paper's MC sizes (<= 1024^2) fit comfortably.
+DMA; until then ``ops.conv2d`` refuses inputs above
+``kernels.RESIDENT_INPUT_MAX_BYTES``.
 """
 from __future__ import annotations
 
@@ -21,9 +22,8 @@ def _conv_kernel(r, bm, bn, a_ref, w_ref, o_ref):
     j = pl.program_id(1)
     row0 = i * bm
     col0 = j * bn
-    tile = pl.load(a_ref, (pl.dslice(row0, bm + r - 1),
-                           pl.dslice(col0, bn + r - 1)))
-    tile = tile.astype(jnp.float32)
+    tile = a_ref[pl.ds(row0, bm + r - 1),
+                 pl.ds(col0, bn + r - 1)].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
     acc = jnp.zeros((bm, bn), jnp.float32)
     for di in range(r):
@@ -34,7 +34,7 @@ def _conv_kernel(r, bm, bn, a_ref, w_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
 def conv2d(a: jax.Array, w: jax.Array, *, bm: int = 128, bn: int = 128,
-           interpret: bool = True) -> jax.Array:
+           interpret: bool) -> jax.Array:
     """a: [m, n], w: [r, r] -> valid correlation [m-r+1, n-r+1] (padded to
     block multiples by ops.py)."""
     m, n = a.shape

@@ -6,7 +6,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import Aval, resolve_interpret
+from repro.kernels import Aval, check_resident_input, resolve_interpret
 from repro.kernels.conv2d import conv2d as _kernel
 from repro.kernels.conv2d import ref as _ref
 
@@ -33,5 +33,6 @@ def conv2d(a: jax.Array, w: jax.Array, *, bm: int = 128, bn: int = 128,
     om, on = m - r + 1, n - r + 1
     pm, pn = (-om) % bm, (-on) % bn
     ap = jnp.pad(a, ((0, pm), (0, pn))) if (pm or pn) else a
+    check_resident_input("conv2d", ap.shape, ap.dtype)
     out = _kernel.conv2d(ap, w, bm=bm, bn=bn, interpret=interpret)
     return out[:om, :on]

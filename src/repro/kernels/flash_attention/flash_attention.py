@@ -21,48 +21,20 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _fa_kernel(scale, causal, window, bq, bk, sk_orig,
-               q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref):
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    i = pl.program_id(1)
-    q = q_ref[0, 0].astype(jnp.float32)              # [bq, d]
-    k = k_ref[0, 0].astype(jnp.float32)              # [bk, d]
-    v = v_ref[0, 0].astype(jnp.float32)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+def _mask(i, j, bq, bk, sk_orig, causal, window):
     q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    ok = k_pos < sk_orig                             # padded keys invisible
+    ok = k_pos < sk_orig
     if causal:
         ok &= k_pos <= q_pos
     if window > 0:
         ok &= q_pos - k_pos < window
-    s = jnp.where(ok, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _done():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    return ok
 
 
 def _fa_fwd_kernel(scale, causal, window, bq, bk, sk_orig,
                    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref):
-    """Forward that also emits the row log-sum-exp (for the backward)."""
+    """Forward; also emits the row log-sum-exp the backward reads."""
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -76,14 +48,7 @@ def _fa_fwd_kernel(scale, causal, window, bq, bk, sk_orig,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    ok = k_pos < sk_orig
-    if causal:
-        ok &= k_pos <= q_pos
-    if window > 0:
-        ok &= q_pos - k_pos < window
-    s = jnp.where(ok, s, NEG_INF)
+    s = jnp.where(_mask(i, j, bq, bk, sk_orig, causal, window), s, NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1))
     alpha = jnp.exp(m_prev - m_new)
@@ -97,18 +62,7 @@ def _fa_fwd_kernel(scale, causal, window, bq, bk, sk_orig,
     def _done():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
-
-
-def _mask(i, j, bq, bk, sk_orig, causal, window):
-    q_pos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    ok = k_pos < sk_orig
-    if causal:
-        ok &= k_pos <= q_pos
-    if window > 0:
-        ok &= q_pos - k_pos < window
-    return ok
+        lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[None, :]
 
 
 def _fa_bwd_dq_kernel(scale, causal, window, bq, bk, sk_orig,
@@ -126,8 +80,8 @@ def _fa_bwd_dq_kernel(scale, causal, window, bq, bk, sk_orig,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    lse = lse_ref[0, 0, 0]
+    delta = delta_ref[0, 0, 0]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     ok = _mask(i, j, bq, bk, sk_orig, causal, window)
     p = jnp.where(ok, jnp.exp(s - lse[:, None]), 0.0)
@@ -156,8 +110,8 @@ def _fa_bwd_dkv_kernel(scale, causal, window, bq, bk, sk_orig,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]
-    delta = delta_ref[0, 0]
+    lse = lse_ref[0, 0, 0]
+    delta = delta_ref[0, 0, 0]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     ok = _mask(i, j, bq, bk, sk_orig, causal, window)
     p = jnp.where(ok, jnp.exp(s - lse[:, None]), 0.0)
@@ -175,8 +129,10 @@ def _fa_bwd_dkv_kernel(scale, causal, window, bq, bk, sk_orig,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "bq", "bk", "sk_orig", "interpret"))
 def flash_attention_fwd(q, k, v, *, causal=True, window=0, bq=256, bk=256,
-                        sk_orig=0, interpret=True):
-    """Returns (out [B,H,Sq,D], lse [B,H,Sq]) — forward with residuals."""
+                        sk_orig=0, interpret):
+    """Returns (out [B,H,Sq,D], lse [B,H,1,Sq]) — forward with residuals.
+    The row statistic keeps a unit axis so its block's last two dims
+    (1, bq) are legal on the chip's (8, 128) tiling."""
     b, h, sq, d = q.shape
     _, kv, sk, _ = k.shape
     group = h // kv
@@ -187,7 +143,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, bq=256, bk=256,
     return pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-                   jax.ShapeDtypeStruct((b, h, sq), jnp.float32)),
+                   jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32)),
         grid=(b * h, sq // bq, sk // bk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda bh, i, j: (bh // h, bh % h, i, 0)),
@@ -198,7 +154,8 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, bq=256, bk=256,
         ],
         out_specs=(
             pl.BlockSpec((1, 1, bq, d), lambda bh, i, j: (bh // h, bh % h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda bh, i, j: (bh // h, bh % h, i)),
+            pl.BlockSpec((1, 1, 1, bq),
+                         lambda bh, i, j: (bh // h, bh % h, 0, i)),
         ),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
@@ -212,9 +169,9 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, bq=256, bk=256,
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "bq", "bk", "sk_orig", "interpret"))
 def flash_attention_bwd(q, k, v, do, lse, delta, *, causal=True, window=0,
-                        bq=256, bk=256, sk_orig=0, interpret=True):
-    """Returns (dq [B,H,Sq,D], dk, dv per-q-head [B,H,Sk,D]) — the caller
-    group-sums dk/dv over GQA groups."""
+                        bq=256, bk=256, sk_orig=0, interpret):
+    """lse, delta: [B,H,1,Sq].  Returns (dq [B,H,Sq,D], dk, dv per-q-head
+    [B,H,Sk,D]) — the caller group-sums dk/dv over GQA groups."""
     b, h, sq, d = q.shape
     _, kv, sk, _ = k.shape
     group = h // kv
@@ -222,7 +179,7 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal=True, window=0,
     scale = d ** -0.5
     q_idx = lambda bh, i, j: (bh // h, bh % h, i, 0)
     kv_idx = lambda bh, i, j: (bh // h, (bh % h) // group, j, 0)
-    row_idx = lambda bh, i, j: (bh // h, bh % h, i)
+    row_idx = lambda bh, i, j: (bh // h, bh % h, 0, i)
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, scale, causal, window, bq, bk,
                           sk_orig),
@@ -233,8 +190,8 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal=True, window=0,
             pl.BlockSpec((1, 1, bk, d), kv_idx),
             pl.BlockSpec((1, 1, bk, d), kv_idx),
             pl.BlockSpec((1, 1, bq, d), q_idx),
-            pl.BlockSpec((1, 1, bq), row_idx),
-            pl.BlockSpec((1, 1, bq), row_idx),
+            pl.BlockSpec((1, 1, 1, bq), row_idx),
+            pl.BlockSpec((1, 1, 1, bq), row_idx),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), q_idx),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -245,7 +202,7 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal=True, window=0,
     q_idx2 = lambda bh, j, i: (bh // h, bh % h, i, 0)
     kv_idx2 = lambda bh, j, i: (bh // h, (bh % h) // group, j, 0)
     kvh_idx2 = lambda bh, j, i: (bh // h, bh % h, j, 0)
-    row_idx2 = lambda bh, j, i: (bh // h, bh % h, i)
+    row_idx2 = lambda bh, j, i: (bh // h, bh % h, 0, i)
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, scale, causal, window, bq, bk,
                           sk_orig),
@@ -257,8 +214,8 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal=True, window=0,
             pl.BlockSpec((1, 1, bk, d), kv_idx2),
             pl.BlockSpec((1, 1, bk, d), kv_idx2),
             pl.BlockSpec((1, 1, bq, d), q_idx2),
-            pl.BlockSpec((1, 1, bq), row_idx2),
-            pl.BlockSpec((1, 1, bq), row_idx2),
+            pl.BlockSpec((1, 1, 1, bq), row_idx2),
+            pl.BlockSpec((1, 1, 1, bq), row_idx2),
         ],
         out_specs=(pl.BlockSpec((1, 1, bk, d), kvh_idx2),
                    pl.BlockSpec((1, 1, bk, d), kvh_idx2)),
@@ -267,44 +224,3 @@ def flash_attention_bwd(q, k, v, do, lse, delta, *, causal=True, window=0,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "bq", "bk", "sk_orig", "interpret"))
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, window: int = 0,
-                    bq: int = 256, bk: int = 256,
-                    sk_orig: int = 0, interpret: bool = True) -> jax.Array:
-    """q: [B, H, Sq, D]; k, v: [B, KV, Sk, D] with H % KV == 0.
-
-    Sq % bq == 0 and Sk % bk == 0 (ops.py pads; ``sk_orig`` masks the pad).
-    """
-    b, h, sq, d = q.shape
-    _, kv, sk, _ = k.shape
-    assert h % kv == 0 and sq % bq == 0 and sk % bk == 0
-    group = h // kv
-    sk_orig = sk_orig or sk
-    scale = d ** -0.5
-    kernel = functools.partial(_fa_kernel, scale, causal, window, bq, bk,
-                               sk_orig)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        grid=(b * h, sq // bq, sk // bk),
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d),
-                         lambda bh, i, j: (bh // h, bh % h, i, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda bh, i, j: (bh // h, (bh % h) // group, j, 0)),
-            pl.BlockSpec((1, 1, bk, d),
-                         lambda bh, i, j: (bh // h, (bh % h) // group, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda bh, i, j: (bh // h, bh % h, i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v)

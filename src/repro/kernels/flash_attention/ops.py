@@ -66,7 +66,7 @@ def _attention_bwd(causal, window, bq, bk, interpret, res, dout):
     dop = jnp.pad(dout, ((0, 0), (0, 0), (0, qp.shape[2] - sq), (0, 0)))
     # delta_i = rowsum(do * o) (cheap, jnp)
     delta = jnp.sum(dop.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)
+                    axis=-1)[:, :, None, :]
     dq, dkh, dvh = _kernel.flash_attention_bwd(
         qp, kp, vp, dop, lse, delta, causal=causal, window=window,
         bq=bq, bk=bk, sk_orig=sk, interpret=interpret)

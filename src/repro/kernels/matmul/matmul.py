@@ -27,9 +27,10 @@ def _mm_kernel(a_ref, b_ref, o_ref, acc_ref):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def matmul(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
-           bk: int = 128, interpret: bool = True) -> jax.Array:
+           bk: int = 128, interpret: bool) -> jax.Array:
     """a: [m, k] @ b: [k, n]; dims must be multiples of the block shape
-    (ops.py pads).  interpret=True validates on CPU; False targets TPU."""
+    (ops.py pads).  interpret=True validates on CPU; False targets TPU,
+    where bm must be a multiple of 8 and bn, bk multiples of 128."""
     m, k = a.shape
     k2, n = b.shape
     assert k == k2, (a.shape, b.shape)

@@ -34,5 +34,6 @@ def matvec(a: jax.Array, x: jax.Array, *, bm: int = 256, bk: int = 512,
     pm, pk = (-m) % bm, (-k) % bk
     ap = jnp.pad(a, ((0, pm), (0, pk))) if (pm or pk) else a
     xp = jnp.pad(x, (0, pk)) if pk else x
-    return _kernel.matvec(ap, xp.astype(ap.dtype), bm=bm, bk=bk,
-                          interpret=interpret)[:m]
+    out = _kernel.matvec(ap, xp.astype(ap.dtype)[None, :], bm=bm, bk=bk,
+                         interpret=interpret)
+    return out[:m, 0]

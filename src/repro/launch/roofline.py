@@ -4,6 +4,8 @@
   memory term     = HLO_bytes / (chips * HBM_bw)
   collective term = collective_bytes / (chips * link_bw)
 
+with the peaks read from ``PEAKS`` by the chip's ``device_kind``.
+
 ``compiled.cost_analysis()`` is *per-device* post-SPMD (verified empirically:
 a 2x16x32x64 einsum over 8 devices reports ~65536/8 flops), so global =
 per-device * chips and the task formulas reduce to per-device / per-chip-*.
@@ -19,10 +21,6 @@ from typing import Optional
 
 import numpy as np
 
-# TPU v5e-class hardware constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -33,6 +31,34 @@ _DTYPE_BYTES = {
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
                      "all-to-all", "collective-permute")
+
+
+@dataclasses.dataclass(frozen=True)
+class Peak:
+    """Published per-chip peak rates of one device kind."""
+    flops_bf16: float        # FLOP/s
+    hbm_bytes_per_s: float
+    ici_bytes_per_s: float   # per link
+    source: str
+
+
+# keyed by jax's ``device.device_kind``
+PEAKS = {
+    "TPU v5 lite": Peak(
+        flops_bf16=197e12, hbm_bytes_per_s=819e9,
+        # 1,600 Gbit/s of chip-to-chip interconnect over 4 links
+        ici_bytes_per_s=50e9,
+        source="Google Cloud documentation, TPU v5e"),
+}
+
+
+def peak_for(device_kind: str) -> Peak:
+    """The peak table entry of ``device_kind``; an unknown kind is an error,
+    never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peak rates for device kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 def shape_bytes(shape_str: str) -> int:
@@ -99,16 +125,18 @@ class RooflineReport:
 
 def analyze(arch: str, shape: str, mesh_name: str, chips: int,
             cost: dict, hlo_text: str, model_flops: float,
-            memory_stats: Optional[dict] = None) -> RooflineReport:
+            memory_stats: Optional[dict] = None, *,
+            device_kind: str) -> RooflineReport:
     from repro.launch import hlo_analysis
+    peak = peak_for(device_kind)
     totals = hlo_analysis.analyze_hlo(hlo_text)
     flops = totals.flops
     bytes_accessed = totals.hbm_bytes
     coll = {k: float(v) for k, v in totals.collective_bytes.items()}
     coll_total = float(sum(coll.values()))
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_accessed / HBM_BW
-    collective_s = coll_total / ICI_BW
+    compute_s = flops / peak.flops_bf16
+    memory_s = bytes_accessed / peak.hbm_bytes_per_s
+    collective_s = coll_total / peak.ici_bytes_per_s
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     hlo_global = flops * chips

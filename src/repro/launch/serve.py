@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.models import build_model
 from repro.serve.decode import ServeConfig, generate
@@ -28,6 +29,7 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     if args.reduced:

@@ -26,6 +26,7 @@ import time
 import jax
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.data.pipeline import DataConfig, DataState, Pipeline
 from repro.dist import sharding as shd
@@ -77,6 +78,7 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch = get_arch(args.arch)
     if args.reduced:

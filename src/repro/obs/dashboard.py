@@ -28,7 +28,6 @@ import os
 import time
 from typing import Optional, Sequence
 
-from repro.bench.history import discover, load_row
 from repro.obs.cards import build_cards, load_telemetry_docs
 from repro.obs.slo import DEFAULT_SERVE_SLOS, evaluate_slos
 
@@ -331,6 +330,10 @@ def _section(title: str, body: str, note: str = "") -> str:
 
 
 def _bench_section(results_dir: str) -> str:
+    # imported here: repro.bench imports the workloads, which import
+    # repro.api, which imports repro.obs
+    from repro.bench.history import discover, load_row
+
     patterns = (os.path.join(results_dir, "bench*.json"),
                 "benchmarks/*bench*.json")
     rows = [load_row(p) for p in discover(patterns)]

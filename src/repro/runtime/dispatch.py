@@ -14,6 +14,12 @@ measured instead (see ``DispatchPolicy.confidence_gate``).
 With ``policy.online=True`` every dispatch also records the *actual* wall
 time of the chosen variant and hands it to the ``OnlineRefiner``, which
 refits incrementally and tracks rolling MAPE (see ``online.py``).
+
+A dispatcher built with ``device=`` (a ``jax.Device``) is bound to that
+chip: its operands are placed there before any variant runs, so every
+variant executes on it.  ``repro.api`` compiles one dispatcher per chip
+into a multi-chip program and moves values between them with
+``jax.device_put``.
 """
 from __future__ import annotations
 
@@ -70,8 +76,9 @@ class Dispatcher:
     def __init__(self, registry: Optional[KernelRegistry] = None,
                  cache: Optional[TuningCache] = None,
                  policy: Optional[DispatchPolicy] = None,
-                 telemetry=None):
+                 telemetry=None, device: Optional[jax.Device] = None):
         self.registry = registry or default_registry()
+        self.device = device
         self.cache = cache or TuningCache()
         self.policy = policy or DispatchPolicy()
         self.refiner = OnlineRefiner(self.cache, OnlineConfig(
@@ -139,6 +146,9 @@ class Dispatcher:
     # -- the dispatch path ---------------------------------------------------
     def dispatch(self, kernel: str, *args, **kwargs):
         t0 = time.perf_counter()
+        if self.device is not None:
+            # a no-op for operands already on this chip
+            args = tuple(jax.device_put(a, self.device) for a in args)
         tel = self._telemetry
         rk = self.registry.get(kernel)
         params = rk.params_of(*args, **kwargs)

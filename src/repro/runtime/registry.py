@@ -135,7 +135,9 @@ def _matmul() -> RegisteredKernel:
     ref = jax.jit(lambda a, b: ops.matmul(a, b, use_kernel=False))
     variants = [Variant("matmul", "ref",
                         lambda args, p: ref(*args), feat(0.0, 0.0), flops)]
-    for blk in (32, 128):
+    # Pallas variants are named pallas_<block edge>; on the TPU a block must
+    # tile (8, 128), so no edge is below 128
+    for blk in (128, 256):
         call = jax.jit(lambda a, b, _blk=blk: ops.matmul(
             a, b, bm=_blk, bn=_blk, bk=_blk))
         variants.append(Variant(
@@ -177,13 +179,13 @@ def _conv2d() -> RegisteredKernel:
         return lambda p: [p["m"], p["n"], p["r"], block, pallas]
 
     ref = jax.jit(lambda a, w: ops.conv2d(a, w, use_kernel=False))
-    pall = jax.jit(lambda a, w: ops.conv2d(a, w, bm=32, bn=32))
+    pall = jax.jit(lambda a, w: ops.conv2d(a, w, bm=128, bn=128))
     return RegisteredKernel(
         "conv2d", ops.abstract_params, ("m", "n", "r", "block", "pallas"),
         (Variant("conv2d", "ref", lambda args, p: ref(*args),
                  feat(0.0, 0.0), flops),
-         Variant("conv2d", "pallas_32", lambda args, p: pall(*args),
-                 feat(32.0, 1.0), flops)),
+         Variant("conv2d", "pallas_128", lambda args, p: pall(*args),
+                 feat(128.0, 1.0), flops)),
         abstract_params=ops.abstract_params, out_aval=ops.out_aval)
 
 
@@ -197,16 +199,16 @@ def _maxpool() -> RegisteredKernel:
         return lambda p: [p["m"], p["n"], p["r"], p["s"], block, pallas]
 
     ref = jax.jit(ref_mod.maxpool, static_argnames=("r", "s"))
-    pall = jax.jit(lambda a, r, s: ops.maxpool(a, r=r, s=s, bm=32, bn=32),
+    pall = jax.jit(lambda a, r, s: ops.maxpool(a, r=r, s=s, bm=128, bn=128),
                    static_argnames=("r", "s"))
     return RegisteredKernel(
         "maxpool", ops.abstract_params, ("m", "n", "r", "s", "block", "pallas"),
         (Variant("maxpool", "ref",
                  lambda args, p: ref(args[0], r=p["r"], s=p["s"]),
                  feat(0.0, 0.0), flops),
-         Variant("maxpool", "pallas_32",
+         Variant("maxpool", "pallas_128",
                  lambda args, p: pall(args[0], r=p["r"], s=p["s"]),
-                 feat(32.0, 1.0), flops)),
+                 feat(128.0, 1.0), flops)),
         abstract_params=ops.abstract_params, out_aval=ops.out_aval)
 
 

@@ -57,10 +57,12 @@ from repro.exec.trace import ExecutionTrace
 from repro.kernels import Aval
 from repro.obs.memory import (MemoryLedger, check_capacity, fold_memory,
                               memory_plan, predicted_peak_bytes)
+from repro.obs.telemetry import trace_span
 from repro.runtime.cache import shape_bucket, shape_class
 from repro.runtime.online import OnlineConfig, OnlineRefiner
 
 EXECUTORS = ("sequential", "async", "adaptive")
+CALL_SPAN = "program.call"      # around each call's execution
 
 
 def _resolve_devices(devices, policy) -> dict:
@@ -135,7 +137,7 @@ def compile_program(program: Program, devices=None, policy=None,
     (decision counters, gate events, per-kernel residuals — attached only
     where none is set, an explicitly instrumented dispatcher keeps its
     own), the comm model, the per-device refiners (refit events), the
-    executor (steals, queue depths, transfer waits), and each call's
+    executor (steals, queue depths), and each call's
     predicted-vs-realized makespan."""
     if executor not in EXECUTORS:
         raise ValueError(f"executor must be one of {EXECUTORS}, "
@@ -597,12 +599,13 @@ class CompiledProgram:
             self.last_memory = ledger
             ledger.start()
         t0 = time.perf_counter()
-        if mode == "adaptive":
-            self._run_adaptive(env, ledger)
-        elif mode == "async":
-            self._run_async(env, ledger)
-        else:
-            self._run_sequential(env, ledger)
+        with trace_span(CALL_SPAN):
+            if mode == "adaptive":
+                self._run_adaptive(env, ledger)
+            elif mode == "async":
+                self._run_async(env, ledger)
+            else:
+                self._run_sequential(env, ledger)
         fold_memory(self.telemetry, ledger, self.predicted_peak_bytes)
         if self.telemetry is not None:
             wall = time.perf_counter() - t0

@@ -47,6 +47,9 @@ from concurrent.futures import Future
 from typing import Callable, Mapping, Optional, Sequence
 
 from repro.exec.trace import ExecutionTrace
+from repro.obs.telemetry import trace_span
+
+TASK_SPAN = "exec.task"         # around each task's body, on its worker
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,9 +117,9 @@ class AsyncExecutor:
     after every completed compute task — the online-feedback hook
     ``repro.api`` wires to ``runtime.online.OnlineRefiner.observe``.
     ``telemetry`` (a ``repro.obs.Telemetry``) makes the run observable:
-    per-lane queue-depth gauge series, queue-wait histograms (transfers
-    keyed by their bus/link lane), and steal instants carrying the priced
-    alternatives the decision weighed.
+    per-lane queue-depth gauge series and steal instants carrying the
+    priced alternatives the decision weighed.  Each task's body is an
+    ``exec.task`` span on the profiler's trace, on its worker's thread.
     """
 
     def __init__(self, tracer: Optional[ExecutionTrace] = None,
@@ -265,7 +268,6 @@ class AsyncExecutor:
         # drained).
         queued: dict = {lane: {} for lane in lanes}   # lane -> {name: est fn}
         running: dict = {}              # task name -> (lane, est fn, t_start)
-        enq_t: dict = {}                # task name -> enqueue clock time
 
         def _est_fn(task: ExecTask, lane: str):
             if task.predict is None:    # transfers / non-adaptive tasks
@@ -299,7 +301,6 @@ class AsyncExecutor:
                 else:
                     lane = task.device
                 queued[lane][task.name] = _est_fn(task, lane)
-                enq_t[task.name] = now
                 depth = len(queued[lane])
             if lane != task.device:
                 if self.tracer is not None:
@@ -355,22 +356,12 @@ class AsyncExecutor:
                 now = self.clock()
                 with lock:
                     est = queued[lane].pop(task.name, None)
-                    t_enq = enq_t.pop(task.name, None)
                     depth = len(queued[lane])
                     if not abort.is_set():
                         running[task.name] = (lane, est or (lambda: 0.0),
                                               now)
                 if tel is not None:
                     tel.gauge(f"exec.queue_depth.{lane}", depth, t=now)
-                    if t_enq is not None:
-                        # queue wait: ready (deps resolved) -> lane free.
-                        # Transfers keyed per lane = the per-bus wait
-                        # histogram the contention model is judged by.
-                        wait = now - t_enq
-                        if task.kind == "transfer":
-                            tel.observe(f"exec.transfer_wait_s.{lane}", wait)
-                        else:
-                            tel.observe("exec.task_wait_s", wait)
                 if abort.is_set():
                     # abort cleanup: a skipped task's future must never be
                     # awaited into a hang — cancel it so readers raise
@@ -379,10 +370,11 @@ class AsyncExecutor:
                 stolen = lane != task.device
                 t0 = self.clock()
                 try:
-                    if stolen:
-                        value = task.run_on(env, lane)
-                    else:
-                        value = task.fn(env)
+                    with trace_span(TASK_SPAN):
+                        if stolen:
+                            value = task.run_on(env, lane)
+                        else:
+                            value = task.fn(env)
                 except BaseException as exc:  # noqa: BLE001 — re-raised in run()
                     fail(task, exc)
                     continue

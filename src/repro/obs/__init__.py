@@ -5,12 +5,15 @@ instant events on the executor's clock, and per-kernel prediction-drift
 status (live MAPE vs the fit-time band).  Every decision point in the
 stack reports into it when one is attached — dispatch modes and gate
 outcomes (``runtime.dispatch``), refits (``runtime.online``), steals,
-queue depths and transfer waits (``exec.executor``), comm-model pricing
+queue depths (``exec.executor``), comm-model pricing
 (``exec.comm``), and predicted-vs-realized makespans (``api.compile_``).
 ``exec.ExecutionTrace.to_chrome(telemetry=...)`` merges gauge series as
 counter tracks and telemetry instants into the task timeline;
 ``python -m repro.obs report`` summarizes a saved telemetry file and
-``--check`` gates on drift.
+``--check`` gates on drift.  The program's own host spans go onto the
+profiler's trace, on the device trace's clock (``trace_span``,
+``trace_step``), and ``compile_counter`` counts JAX's compiles in the
+process.
 
 The second layer rides on the same document: the memory ledger
 (``obs.memory``) accounts per-device live/peak bytes against the
@@ -42,5 +45,6 @@ from repro.obs.report import format_summary
 from repro.obs.slo import (DEFAULT_SERVE_SLOS, SLO, burned, evaluate_slos,
                            format_slos, load_slos)
 from repro.obs.telemetry import (NULL_TELEMETRY, OBS_SCHEMA_VERSION,
-                                 NullTelemetry, Telemetry, as_telemetry,
-                                 summarize_doc)
+                                 CompileCounter, NullTelemetry, Telemetry,
+                                 as_telemetry, compile_counter,
+                                 summarize_doc, trace_span, trace_step)

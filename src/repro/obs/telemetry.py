@@ -23,6 +23,20 @@ with the construction-time value kept as ``epoch`` — the same convention
 ``exec.ExecutionTrace`` uses, so telemetry and execution traces merge
 onto one timeline without re-basing.
 
+Spans have a second home: ``trace_span(name)`` is a
+``jax.profiler.TraceAnnotation``, a host span in the profiler's own trace,
+so it lies on the device trace's clock; ``trace_step(name, step)`` marks
+one step of a loop the same way.  With no profiler running a span costs
+about half a microsecond.  ``Telemetry.span`` enters one too, so every
+span ``repro.obs`` records also shows beside the device's operations.
+The program's spans on the step path (``serve.*``, ``program.call``,
+``exec.task``, ``dispatch.*``) use these alone: names are constants,
+built once per kernel, with no arguments but ``serve.step``'s number.
+
+``compile_counter()`` is the process-wide count of JAX's tracing,
+lowering and compiling (a persistent-cache load counts as a compile),
+fed by one ``jax.monitoring`` listener.
+
 ``NULL_TELEMETRY`` is the near-zero-cost default: every method is a
 no-op, so instrumented code paths run unconditionally without branching
 on ``None`` at each site (call sites on the hottest paths still guard —
@@ -42,6 +56,7 @@ from collections import deque
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.obs.drift import DriftConfig, DriftMonitor
 
@@ -52,6 +67,13 @@ OBS_SCHEMA_VERSION = 1
 MAX_HIST_SAMPLES = 4096
 MAX_SERIES_POINTS = 4096
 MAX_EVENTS = 65536
+
+trace_span = TraceAnnotation
+
+
+def trace_step(name: str, step: int) -> StepTraceAnnotation:
+    """Span of one step of a loop, carrying its number (``step_num``)."""
+    return StepTraceAnnotation(name, step_num=step)
 
 
 class _Histogram:
@@ -131,7 +153,8 @@ class Telemetry:
     def span(self, name: str, cat: str = "span", **args):
         t0 = self.clock()
         try:
-            yield
+            with trace_span(name):
+                yield
         finally:
             t1 = self.clock()
             with self._lock:
@@ -245,9 +268,8 @@ class NullTelemetry(Telemetry):
     def instant(self, name, cat="event", **args):
         pass
 
-    @contextlib.contextmanager
     def span(self, name, cat="span", **args):
-        yield
+        return trace_span(name)
 
     def event(self, name, t0, t1, cat="span", **args):
         pass
@@ -279,6 +301,75 @@ NULL_TELEMETRY = NullTelemetry()
 def as_telemetry(tel: Optional[Telemetry]) -> Telemetry:
     """None-tolerant coercion: ``None`` becomes the shared no-op."""
     return tel if tel is not None else NULL_TELEMETRY
+
+
+# --------------------------------------------------------------------------
+# compile counter (process-wide, as jax.monitoring's listeners are)
+# --------------------------------------------------------------------------
+
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # wraps compile_or_get_cached, so a persistent-cache load counts too
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+
+
+class CompileCounter:
+    """Counts of JAX's tracing, lowering and compiling, each with its
+    interval on the host clock (``time.perf_counter``)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.counts = dict.fromkeys(COMPILE_EVENTS.values(), 0)
+        self._intervals: deque = deque(maxlen=MAX_EVENTS)  # (t0, t1, phase)
+
+    def __call__(self, event: str, duration: float, **_) -> None:
+        phase = COMPILE_EVENTS.get(event)
+        if phase is None:
+            return
+        t1 = time.perf_counter()
+        with self._lock:
+            self.counts[phase] += 1
+            self._intervals.append((t1 - duration, t1, phase))
+
+    def intervals(self) -> list:
+        with self._lock:
+            return list(self._intervals)
+
+    def busy_s(self, until: float = float("inf")) -> float:
+        """Host seconds before ``until`` in which something was traced,
+        lowered or compiled: the union of the intervals, so that a trace
+        nested in another counts once."""
+        total, reach = 0.0, float("-inf")
+        for t0, t1, _ in sorted(self.intervals()):
+            t0, t1 = max(t0, reach), min(t1, until)
+            if t1 > t0:
+                total += t1 - t0
+            reach = max(reach, t1)
+        return total
+
+    def compiles_between(self, lo: float, hi: float) -> int:
+        """Compiles (or cache loads) that ended in ``[lo, hi)``."""
+        return sum(1 for _, t1, phase in self.intervals()
+                   if phase == "compile" and lo <= t1 < hi)
+
+
+_COMPILES: Optional[CompileCounter] = None
+_COMPILES_LOCK = threading.Lock()
+
+
+def compile_counter() -> CompileCounter:
+    """The process's ``CompileCounter``; its listener is registered on the
+    first call, so call this before the first compile to be counted."""
+    global _COMPILES
+    with _COMPILES_LOCK:
+        if _COMPILES is None:
+            import jax.monitoring
+
+            _COMPILES = CompileCounter()
+            jax.monitoring.register_event_duration_secs_listener(_COMPILES)
+        return _COMPILES
 
 
 # --------------------------------------------------------------------------

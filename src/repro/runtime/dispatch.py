@@ -31,10 +31,16 @@ from typing import Optional
 import jax
 import numpy as np
 
+from repro.obs.telemetry import trace_span
 from repro.perfdata.measure import time_callable
 from repro.runtime.cache import TuningCache, shape_bucket
 from repro.runtime.online import OnlineConfig, OnlineRefiner
 from repro.runtime.registry import KernelRegistry, default_registry
+
+# a dispatch's phases on the profiler's trace, inside ``dispatch.<kernel>``
+DECIDE_SPAN = "dispatch.decide"
+LAUNCH_SPAN = "dispatch.launch"
+WAIT_SPAN = "dispatch.wait"
 
 
 @dataclasses.dataclass
@@ -70,6 +76,8 @@ class Selection:
     measured_s: Optional[dict]      # variant -> measured seconds (cold path)
     overhead_s: float               # decision cost (predict/measure + bookkeeping)
     kernel_s: float                 # wall time of the executed variant
+    launched_at: float = 0.0        # perf_counter when its call returned
+    done_at: float = 0.0            # perf_counter when its result was ready
 
 
 class Dispatcher:
@@ -103,6 +111,7 @@ class Dispatcher:
         # Entries carry the cache entry's fit version and die on refit.
         self._decisions: dict[tuple, tuple] = {}
         self._entries: dict[str, object] = {}
+        self._span_names: dict[str, str] = {}
 
     # -- helpers -------------------------------------------------------------
     @property
@@ -144,83 +153,39 @@ class Dispatcher:
         self.cache.save(kernel)
 
     # -- the dispatch path ---------------------------------------------------
+    def _span_name(self, kernel: str) -> str:
+        name = self._span_names.get(kernel)
+        if name is None:
+            name = self._span_names[kernel] = "dispatch." + kernel
+        return name
+
     def dispatch(self, kernel: str, *args, **kwargs):
-        t0 = time.perf_counter()
-        if self.device is not None:
-            # a no-op for operands already on this chip
-            args = tuple(jax.device_put(a, self.device) for a in args)
+        """Run the predicted-best variant of ``kernel``.  On the profiler's
+        trace the call is a ``dispatch.<kernel>`` span holding
+        ``dispatch.decide``, ``dispatch.launch`` (the variant's call, which
+        enqueues its program) and ``dispatch.wait`` (until it is ready)."""
+        with trace_span(self._span_name(kernel)):
+            t0 = time.perf_counter()
+            with trace_span(DECIDE_SPAN):
+                if self.device is not None:
+                    # a no-op for operands already on this chip
+                    args = tuple(jax.device_put(a, self.device) for a in args)
+                rk = self.registry.get(kernel)
+                params = rk.params_of(*args, **kwargs)
+                bucket = shape_bucket(params)
+                entry = self._entry(kernel)
+                idx, mode, predicted, measured, rows, memo_hit = \
+                    self._decide(kernel, rk, entry, args, params, bucket)
+            overhead = time.perf_counter() - t0
+            chosen = rk.variants[idx]
+            with trace_span(LAUNCH_SPAN):
+                out = chosen.call(args, params)
+            t_launched = time.perf_counter()
+            with trace_span(WAIT_SPAN):
+                out = jax.block_until_ready(out)
+            t_done = time.perf_counter()
+        kernel_s = t_done - t0 - overhead
         tel = self._telemetry
-        rk = self.registry.get(kernel)
-        params = rk.params_of(*args, **kwargs)
-        bucket = shape_bucket(params)
-        entry = self._entry(kernel)
-
-        predicted = measured = rows = None
-        memo_hit = False
-        warm = entry.model is not None
-        if warm:
-            # the per-shape memo is checked before anything else: an earlier
-            # decision for this exact shape (predicted OR gated-measured)
-            # stands until the next refit bumps entry.version
-            memo_key = (kernel, tuple(sorted(params.items())))
-            hit = self._decisions.get(memo_key)
-            if hit is not None and hit[0] == entry.version:
-                _, idx, predicted = hit
-                memo_hit = True
-                mode = "predicted"
-                self.n_predicted += 1
-            else:
-                rows = self.registry.feature_rows(kernel, params)
-                pred = entry.predict(rows)
-                predicted = dict(zip(entry.variant_names, pred.tolist()))
-                order = np.argsort(pred)
-                gate = self.policy.confidence_gate \
-                    and bucket not in entry.buckets
-                confident, spread, band = (True, None, None) if not gate \
-                    else self._gate_eval(pred, order, kernel, entry)
-                if confident:
-                    idx = int(order[0])
-                    mode = "predicted"
-                    self.n_predicted += 1
-                    if gate and tel is not None:
-                        tel.count("gate.accept")
-                        tel.count(f"gate.by_kernel.{kernel}.accept")
-                else:
-                    # unseen shape class + near-tie: measure the top-2
-                    cand = [int(i)
-                            for i in order[:self.policy.gate_candidates]]
-                    idx, measured = self._measure(entry, rk, rows, args,
-                                                  params, bucket,
-                                                  candidates=cand)
-                    mode = "gated"
-                    self.n_gated += 1
-                    if tel is not None:
-                        tel.count("gate.reject")
-                        tel.count(f"gate.by_kernel.{kernel}.reject")
-                        tel.instant(f"gate:{kernel}", cat="gate",
-                                    kernel=kernel, reason="near_tie",
-                                    spread_pct=100.0 * spread,
-                                    band_pct=100.0 * band,
-                                    bucket=list(bucket))
-                # memoize either way — a gated dispatch stores the *measured*
-                # winner, so later calls of this shape reuse it instead of
-                # re-trusting the argmin the gate just judged unconfident
-                self._decisions[memo_key] = (entry.version, idx, predicted)
-        elif self.policy.measure_on_cold:
-            rows = self.registry.feature_rows(kernel, params)
-            idx, measured = self._measure(entry, rk, rows, args, params,
-                                          bucket)
-            mode = "measured"
-            self.n_measured += 1
-        else:
-            idx, mode = 0, "default"
-            self.n_default += 1
-
-        overhead = time.perf_counter() - t0
-        chosen = rk.variants[idx]
-        t1 = time.perf_counter()
-        out = jax.block_until_ready(chosen.call(args, params))
-        kernel_s = time.perf_counter() - t1
 
         # online feedback — but never from a first warm execution of a new
         # shape: all variant calls are jit-wrapped, so that wall time is
@@ -251,8 +216,72 @@ class Dispatcher:
         self.selections.append(Selection(
             kernel=kernel, params=params, bucket=bucket, mode=mode,
             chosen=chosen.name, predicted_s=predicted, measured_s=measured,
-            overhead_s=overhead, kernel_s=kernel_s))
+            overhead_s=overhead, kernel_s=kernel_s,
+            launched_at=t_launched, done_at=t_done))
         return out
+
+    def _decide(self, kernel, rk, entry, args, params, bucket) -> tuple:
+        """``(variant index, mode, predicted, measured, rows, memo_hit)``
+        of one dispatch."""
+        tel = self._telemetry
+        predicted = measured = rows = None
+        memo_hit = False
+        if entry.model is not None:
+            # the per-shape memo is checked before anything else: an earlier
+            # decision for this exact shape (predicted OR gated-measured)
+            # stands until the next refit bumps entry.version
+            memo_key = (kernel, tuple(sorted(params.items())))
+            hit = self._decisions.get(memo_key)
+            if hit is not None and hit[0] == entry.version:
+                _, idx, predicted = hit
+                self.n_predicted += 1
+                return idx, "predicted", predicted, None, None, True
+            rows = self.registry.feature_rows(kernel, params)
+            pred = entry.predict(rows)
+            predicted = dict(zip(entry.variant_names, pred.tolist()))
+            order = np.argsort(pred)
+            gate = self.policy.confidence_gate \
+                and bucket not in entry.buckets
+            confident, spread, band = (True, None, None) if not gate \
+                else self._gate_eval(pred, order, kernel, entry)
+            if confident:
+                idx = int(order[0])
+                mode = "predicted"
+                self.n_predicted += 1
+                if gate and tel is not None:
+                    tel.count("gate.accept")
+                    tel.count(f"gate.by_kernel.{kernel}.accept")
+            else:
+                # unseen shape class + near-tie: measure the top-2
+                cand = [int(i)
+                        for i in order[:self.policy.gate_candidates]]
+                idx, measured = self._measure(entry, rk, rows, args,
+                                              params, bucket,
+                                              candidates=cand)
+                mode = "gated"
+                self.n_gated += 1
+                if tel is not None:
+                    tel.count("gate.reject")
+                    tel.count(f"gate.by_kernel.{kernel}.reject")
+                    tel.instant(f"gate:{kernel}", cat="gate",
+                                kernel=kernel, reason="near_tie",
+                                spread_pct=100.0 * spread,
+                                band_pct=100.0 * band,
+                                bucket=list(bucket))
+            # memoize either way — a gated dispatch stores the *measured*
+            # winner, so later calls of this shape reuse it instead of
+            # re-trusting the argmin the gate just judged unconfident
+            self._decisions[memo_key] = (entry.version, idx, predicted)
+        elif self.policy.measure_on_cold:
+            rows = self.registry.feature_rows(kernel, params)
+            idx, measured = self._measure(entry, rk, rows, args, params,
+                                          bucket)
+            mode = "measured"
+            self.n_measured += 1
+        else:
+            idx, mode = 0, "default"
+            self.n_default += 1
+        return idx, mode, predicted, measured, rows, memo_hit
 
     __call__ = dispatch
 

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.registry import Model
+from repro.obs.telemetry import trace_span, trace_step
 # Back-compat re-exports: the admission cost model moved to serve.policy
 # when the decode_step pseudo-kernel split into prefill_step/decode_step.
 from repro.serve.policy import (  # noqa: F401
@@ -49,6 +50,13 @@ from repro.serve.policy import (  # noqa: F401
 _KV_LEAVES = frozenset({"k", "v", "xk", "xv"})
 _RECURRENT_KINDS = frozenset({"mlstm", "slstm", "hybrid"})
 _SUPPORTED_KINDS = frozenset({"attn", "local", "moe"}) | _RECURRENT_KINDS
+
+# the step's spans on the profiler's trace
+STEP_SPAN = "serve.step"
+ADMIT_SPAN = "serve.admit"
+ASSEMBLE_SPAN = "serve.assemble"
+EXECUTE_SPAN = "serve.execute"
+EMIT_SPAN = "serve.emit"
 
 
 @dataclasses.dataclass
@@ -206,23 +214,22 @@ class ContinuousBatcher:
             jnp.int32(self.index), jnp.asarray(self.start))
         return np.asarray(next_tok)
 
-    def step(self) -> bool:
-        """Returns True while there is work."""
+    def _admit_active(self) -> list:
+        """Admit what fits; the active slots (empty: no work)."""
         self._admit()
         active = [i for i, s in enumerate(self.slots) if s is not None]
-        if not active:
-            if not self.queue:
-                return False
+        if not active and self.queue:
             # every slot is drained but the queue head would overflow the
             # shared cache region: all positions are dead tenants, so the
-            # region is reclaimable — rewind and re-admit.
+            # region is reclaimable — rewind and re-admit.  Still empty:
+            # a request that can never fit.
             self.index = 0
             self._admit()
             active = [i for i, s in enumerate(self.slots) if s is not None]
-            if not active:       # a request that can never fit
-                return False
-        tokens = self._assemble(active)
-        next_tok = self._execute(tokens)
+        return active
+
+    def _emit(self, active: list, next_tok: np.ndarray) -> None:
+        """Hand each active slot its token; release finished requests."""
         for i in active:
             req = self.slots[i]
             if self.prompt_left[i] > 1:
@@ -236,9 +243,26 @@ class ContinuousBatcher:
                 req.done = True
                 self.slots[i] = None
                 self._on_done(req, i)
-        self.index += 1
-        self.steps += 1
-        self.busy_slot_steps += len(active)
+
+    def step(self) -> bool:
+        """Returns True while there is work.  On the profiler's trace a step
+        is a ``serve.step`` span carrying its number, holding
+        ``serve.admit``, ``serve.assemble``, ``serve.execute`` and
+        ``serve.emit``."""
+        with trace_step(STEP_SPAN, self.steps):
+            with trace_span(ADMIT_SPAN):
+                active = self._admit_active()
+            if not active:
+                return False
+            with trace_span(ASSEMBLE_SPAN):
+                tokens = self._assemble(active)
+            with trace_span(EXECUTE_SPAN):
+                next_tok = self._execute(tokens)
+            with trace_span(EMIT_SPAN):
+                self._emit(active, next_tok)
+            self.index += 1
+            self.steps += 1
+            self.busy_slot_steps += len(active)
         return True
 
     def run(self, max_steps: int = 100000) -> dict:

@@ -36,6 +36,11 @@ counters):
   request — enough for ``explain`` to rebuild a TTFT waterfall (queue
   wait / prefill / decode / scheduling overhead) per request.
 
+Without a ``Telemetry`` (or with ``NULL_TELEMETRY``) the step path makes
+no telemetry call and the compiled step gets none.  The step's spans on
+the profiler's trace (``ContinuousBatcher.step``, plus ``serve.fetch``
+around the host copy of the step's tokens) are there either way.
+
 A cold cache is not an error: ``ColdCacheError`` from the cost model
 demotes admission to FIFO with a ``serve.admission_fallback`` count, and
 completed requests keep recording split rows so the cache warms up for
@@ -55,7 +60,7 @@ from repro.api.compile_ import compile_program
 from repro.api.ops import TraceBuilder
 from repro.core.nnc import LinearModel
 from repro.kernels import Aval
-from repro.obs.telemetry import as_telemetry
+from repro.obs.telemetry import as_telemetry, trace_span
 from repro.runtime.cache import shape_bucket
 from repro.runtime.dispatch import Dispatcher, DispatchPolicy
 from repro.runtime.registry import (KernelRegistry, RegisteredKernel,
@@ -67,6 +72,7 @@ from repro.serve.policy import (ADMISSION_POLICIES, ColdCacheError,
 
 SERVE_STEP_KERNEL = "serve_step"
 SERVE_STEP_FEATURES = ("slots", "ctx")
+FETCH_SPAN = "serve.fetch"
 
 
 class ServeEngine(ContinuousBatcher):
@@ -112,31 +118,7 @@ class ServeEngine(ContinuousBatcher):
         self.completed: list = []
         self.rejected: list = []
         self._step_reqs: list = []   # (rid, slot, phase) of the live step
-        # KV/slot byte gauges for the memory ledger surface: the cache is
-        # preallocated for max_slots, so totals are static per engine;
-        # serve.kv_live_bytes tracks the occupied-slot share on
-        # admit/release (the number a capacity-aware admission would gate
-        # on).  KV leaves are the per-position k/v planes; everything else
-        # in the cache tree is recurrent per-slot state.
-        self.kv_cache_bytes, self.slot_bytes = self._cache_bytes()
-        self.telemetry.gauge("serve.kv_cache_bytes", self.kv_cache_bytes)
-        self.telemetry.gauge("serve.kv_slot_bytes", self.slot_bytes)
-        self.telemetry.gauge("serve.kv_live_bytes", 0)
         self._compiled = self._compile_step(executor)
-
-    # -- memory accounting ---------------------------------------------------
-    def _cache_bytes(self) -> tuple:
-        """``(total cache bytes, per-slot bytes)`` of the preallocated
-        model cache tree (KV planes + recurrent state, all slot-major)."""
-        leaves = jax.tree_util.tree_leaves(self.cache)
-        total = int(sum(x.size * jnp.dtype(x.dtype).itemsize
-                        for x in leaves))
-        return total, total // max(self.max_slots, 1)
-
-    def _gauge_kv_live(self) -> None:
-        active = sum(1 for s in self.slots if s is not None)
-        self.telemetry.gauge("serve.kv_live_bytes",
-                             active * self.slot_bytes)
 
     # -- predictions ---------------------------------------------------------
     def predict_ttft_s(self, prompt_len: int) -> Optional[float]:
@@ -207,7 +189,7 @@ class ServeEngine(ContinuousBatcher):
         tb.mark_output(tb.add(SERVE_STEP_KERNEL, (tokens0, start0), {}))
         return compile_program(
             tb.program, devices={"serve": dispatcher}, executor=executor,
-            telemetry=self.telemetry)
+            telemetry=self.telemetry if self.telemetry.enabled else None)
 
     def _model_step(self, tokens, start):
         """The serve_step variant body: one jitted model step over the
@@ -218,48 +200,58 @@ class ServeEngine(ContinuousBatcher):
         return next_tok
 
     def _assemble(self, active: list) -> np.ndarray:
-        # snapshot who rides this iteration (and in which phase) before
-        # the base class consumes prompt state — the step span records it
-        self._step_reqs = [
-            {"rid": self.slots[i].rid, "slot": i,
-             "phase": "prefill" if self.prompt_left[i] >= 1 else "decode"}
-            for i in active]
+        if self.telemetry.enabled:
+            # snapshot who rides this iteration (and in which phase) before
+            # the base class consumes prompt state — the step span records it
+            self._step_reqs = [
+                {"rid": self.slots[i].rid, "slot": i,
+                 "phase": "prefill" if self.prompt_left[i] >= 1
+                 else "decode"}
+                for i in active]
         return super()._assemble(active)
 
     def _execute(self, tokens: np.ndarray) -> np.ndarray:
         t0 = time.perf_counter()
-        out = np.asarray(self._compiled(tokens, self.start.copy()))
-        self.telemetry.event(
-            f"engine.step:{self.steps}", t0, time.perf_counter(),
-            cat="serve.step", step=self.steps, requests=self._step_reqs)
+        out = self._compiled(tokens, self.start.copy())
+        with trace_span(FETCH_SPAN):
+            out = np.asarray(out)
+        if self.telemetry.enabled:
+            self.telemetry.event(
+                f"engine.step:{self.steps}", t0, time.perf_counter(),
+                cat="serve.step", step=self.steps, requests=self._step_reqs)
         return out
 
     # -- queue + lifecycle hooks ---------------------------------------------
     def submit(self, req) -> bool:
+        tel = self.telemetry
         if len(self.queue) >= self.max_queue:
             req.rejected = True
             self.rejected.append(req)
-            self.telemetry.count("serve.requests_rejected")
+            if tel.enabled:
+                tel.count("serve.requests_rejected")
             return False
         if getattr(req, "submitted_s", None) is None:
             req.submitted_s = time.perf_counter()
-        self.telemetry.instant(
-            f"request.arrival:{req.rid}", cat="serve.request", rid=req.rid,
-            prompt=len(req.prompt), max_new=req.max_new)
+        if tel.enabled:
+            tel.instant(f"request.arrival:{req.rid}", cat="serve.request",
+                        rid=req.rid, prompt=len(req.prompt),
+                        max_new=req.max_new)
         if self._split_model is not None:
             req.predicted_s = self._split_model.request_seconds(
                 len(req.prompt), req.max_new)
         super().submit(req)
-        self.telemetry.gauge("serve.queue_depth", len(self.queue))
+        if tel.enabled:
+            tel.gauge("serve.queue_depth", len(self.queue))
         return True
 
     def _on_admit(self, req, slot: int) -> None:
         now = time.perf_counter()
         req.admitted_s = now
         req.slot = slot
+        if not self.telemetry.enabled:
+            return
         submitted = getattr(req, "submitted_s", None)
         self.telemetry.gauge("serve.queue_depth", len(self.queue))
-        self._gauge_kv_live()
         self.telemetry.instant(
             f"admission:{req.rid}", cat="admission", rid=req.rid,
             slot=slot, policy=self.policy_name,
@@ -271,6 +263,9 @@ class ServeEngine(ContinuousBatcher):
         now = time.perf_counter()
         if first:
             req.first_token_s = now
+        if not self.telemetry.enabled:
+            return
+        if first:
             self.telemetry.instant(f"first_token:{req.rid}",
                                    cat="serve.request", rid=req.rid)
             submitted = getattr(req, "submitted_s", None)
@@ -288,6 +283,10 @@ class ServeEngine(ContinuousBatcher):
         now = time.perf_counter()
         req.finished_s = now
         self.completed.append(req)
+        if self.record_rows:
+            self._record_split_rows(req, now)
+        if not self.telemetry.enabled:
+            return
         self.telemetry.instant(f"request.done:{req.rid}",
                                cat="serve.request", rid=req.rid,
                                tokens=len(req.generated))
@@ -299,9 +298,6 @@ class ServeEngine(ContinuousBatcher):
                 if self._split_model is not None else None
             self.telemetry.residual("serve.request", predicted,
                                     now - admitted, fit_band_pct=band)
-        if self.record_rows:
-            self._record_split_rows(req, now)
-        self._gauge_kv_live()
 
     def _record_split_rows(self, req, now: float) -> None:
         """Split the completed request's measured wall time into one

@@ -585,3 +585,65 @@ def test_run_adaptive_saves_merged_trace_and_telemetry(tmp_path):
     # legitimate outcome of the mis-seeded scenario, not a failure here)
     assert report_main(["report", section["telemetry_path"],
                         "--check"]) in (0, 1)
+
+
+# --------------------------------------------------------------------------
+# spans on the profiler's trace; the compile counter
+# --------------------------------------------------------------------------
+
+def test_dispatch_span_holds_decide_launch_wait_in_order(tmp_path):
+    from _trace import host_spans, profiled
+
+    d = _fitted_dispatcher(tmp_path, slowdown=10.0)
+    a = jnp.ones((128,), jnp.float32)
+    d.dispatch("toy", a)                     # jit compiles outside the trace
+    with profiled(tmp_path / "trace"):
+        d.dispatch("toy", a)
+    spans = host_spans(tmp_path / "trace", ("dispatch.",))
+    assert [s.name for s in spans] == ["dispatch.toy", "dispatch.decide",
+                                       "dispatch.launch", "dispatch.wait"]
+    outer, decide, launch, wait = spans
+    assert all(outer.holds(s) for s in (decide, launch, wait))
+    assert decide.end <= launch.start and launch.end <= wait.start
+    sel = d.selections[-1]
+    # the dispatcher's own record of the same phases, on perf_counter
+    assert 0 < sel.overhead_s and sel.launched_at <= sel.done_at
+    assert sel.kernel_s >= sel.done_at - sel.launched_at
+
+
+def test_telemetry_spans_reach_the_profiler_trace(tmp_path):
+    from _trace import host_spans, profiled
+
+    tel = Telemetry()
+    with profiled(tmp_path / "trace"):
+        with tel.span("obs.recorded"):
+            pass
+        with NULL_TELEMETRY.span("obs.null"):
+            pass
+    names = [s.name for s in host_spans(tmp_path / "trace", ("obs.",))]
+    assert names == ["obs.recorded", "obs.null"]
+    assert [e["name"] for e in tel.events()] == ["obs.recorded"]
+
+
+def test_compile_counter_rises_on_a_new_shape_not_on_a_repeat():
+    import jax
+
+    from repro.obs import compile_counter
+
+    counter = compile_counter()
+    assert compile_counter() is counter      # one listener per process
+    f = jax.jit(lambda x: x * 3.0 + 1.0)
+    x = jnp.ones((7, 3), jnp.float32)
+    before = dict(counter.counts)
+    t0 = time.perf_counter()
+    f(x).block_until_ready()
+    after = dict(counter.counts)
+    assert after["compile"] == before["compile"] + 1
+    assert after["trace"] > before["trace"]
+    assert after["lower"] > before["lower"]
+    assert counter.compiles_between(t0, time.perf_counter()) == 1
+    busy = counter.busy_s()
+    assert counter.busy_s(until=t0) < busy
+    f(x).block_until_ready()
+    assert counter.counts == after
+    assert counter.busy_s() == busy

@@ -534,3 +534,73 @@ def test_ring_decode_multidevice_parity():
         capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "RING_DECODE_OK" in r.stdout
+
+
+# --------------------------------------------------------------------------
+# the step's spans on the profiler's trace; no telemetry, no calls
+# --------------------------------------------------------------------------
+
+def test_step_spans_on_the_profiler_trace(tmp_path, tiny_model):
+    """Three engine steps under the profiler: one numbered ``serve.step``
+    each, holding admit < assemble < execute < emit; the execute phase
+    holds the compiled program's call and the host copy of its tokens, and
+    the dispatch runs inside ``exec.task`` on the executor's worker."""
+    from _trace import host_spans, profiled
+
+    model, params = tiny_model
+    eng = ServeEngine(model, TuningCache(root=str(tmp_path / "tc")),
+                      params=params, max_slots=2, max_seq=64,
+                      admission="fifo", record_rows=False)
+    eng.submit(ServeRequest(rid=0, prompt=[3, 4], max_new=8))
+    eng.step()                               # compiles outside the trace
+    with profiled(tmp_path / "trace"):
+        for _ in range(3):
+            assert eng.step()
+    spans = host_spans(tmp_path / "trace", (
+        "serve.", "program.", "exec.", "dispatch."))
+    steps = [s for s in spans if s.name == "serve.step"]
+    assert [s.stats["step_num"] for s in steps] == [1, 2, 3]
+    phases = ("serve.admit", "serve.assemble", "serve.execute", "serve.emit")
+    for step in steps:
+        inside = [s for s in spans if step.holds(s) and s is not step]
+        top = [s for s in inside if s.thread == step.thread
+               and s.name in phases]
+        assert [s.name for s in top] == list(phases)
+        assert all(a.end <= b.start for a, b in zip(top, top[1:]))
+        execute = top[2]
+        names = [s.name for s in inside
+                 if execute.holds(s) and s is not execute]
+        assert names == ["program.call", "exec.task", "dispatch.serve_step",
+                         "dispatch.decide", "dispatch.launch",
+                         "dispatch.wait", "serve.fetch"]
+        by = {s.name: s for s in inside}
+        assert by["program.call"].holds(by["exec.task"])
+        assert by["exec.task"].holds(by["dispatch.serve_step"])
+        assert by["program.call"].end <= by["serve.fetch"].start
+        worker = by["exec.task"].thread
+        assert worker != step.thread
+        assert all(s.thread == worker for s in inside
+                   if s.name.startswith("dispatch."))
+
+
+def test_engine_without_telemetry_makes_no_telemetry_call(
+        tmp_path, tiny_model, monkeypatch):
+    from repro.obs.telemetry import NullTelemetry
+
+    calls = []
+    for name in ("count", "gauge", "observe", "instant", "event",
+                 "residual", "span"):
+        monkeypatch.setattr(
+            NullTelemetry, name,
+            lambda self, *a, _n=name, **k: calls.append(_n))
+    model, params = tiny_model
+    eng = ServeEngine(model, TuningCache(root=str(tmp_path / "tc")),
+                      params=params, max_slots=2, max_seq=64,
+                      admission="fifo", record_rows=False)
+    assert eng._compiled.telemetry is None   # None stays None below it
+    for i in range(3):
+        eng.submit(ServeRequest(rid=i, prompt=[1 + i] * 2, max_new=3))
+    while eng.step():
+        pass
+    assert len(eng.completed) == 3
+    assert calls == []

@@ -44,16 +44,20 @@ def _causal_skip_possible(step: int, n: int, s_loc: int,
 
 def ring_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                 cache_index, *, mesh=None, axis_name: str = "model",
-                window: int = 0, start=None) -> jax.Array:
+                window: int = 0, start=None, layer=None) -> jax.Array:
     """Decode-time ring attention over a sequence-sharded KV cache.
 
-    q: [B,1,H,D]; caches: [B,Smax,KV,D] with ``cache_seq`` sharded over
-    ``axis_name`` (``serve_rules(long_context=True)``).  Unlike the
-    prefill ring, the KV shards never move: each device computes grouped
-    online-softmax *stats* (acc, m, l) over its resident shard and the
-    tiny [B,KV,G]-shaped stats rotate around the ring instead of the
-    multi-GB cache — per-step collective traffic is O(B*H*D), not
-    O(Smax*KV*D/n).
+    q: [B,1,H,D]; caches: [B,KV,Smax,D], or with ``layer`` the stacked
+    [L,B,KV,Smax,D] leaves of a layer scan, with ``cache_seq`` sharded over
+    ``axis_name`` (``serve_rules(long_context=True)``).  A stacked cache
+    enters the SPMD body whole and each device reads layer ``layer`` of its
+    own shard there, so no layer is sliced out of the stack first.
+
+    Unlike the prefill ring, the KV shards never move: each device
+    computes grouped online-softmax *stats* (acc, m, l) over its resident
+    shard and the tiny [B,KV,G]-shaped stats rotate around the ring
+    instead of the multi-GB cache — per-step collective traffic is
+    O(B*H*D), not O(Smax*KV*D/n).
 
     A shard whose keys are all masked for some row yields m = NEG_INF
     (finite, so exp(m - m) = 1, no NaN); its poisoned (acc, l) are
@@ -64,14 +68,15 @@ def ring_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     """
     if mesh is None:
         mesh = active_mesh()
+    from repro.models.attention import attend_decode, layer_of
     b, one, h, d = q.shape
-    smax, kv = k_cache.shape[1], k_cache.shape[2]
+    kv, smax = k_cache.shape[-3], k_cache.shape[-2]
     sizes = _axis_sizes(mesh) if mesh is not None else {}
     n = sizes.get(axis_name, 1)
     if mesh is None or n <= 1 or smax % n != 0:
-        from repro.models.attention import attend_decode
-        return attend_decode(q, k_cache, v_cache, cache_index,
-                             window=window, start=start)
+        return attend_decode(q, layer_of(k_cache, layer).astype(q.dtype),
+                             layer_of(v_cache, layer).astype(q.dtype),
+                             cache_index, window=window, start=start)
     g = h // kv
     s_loc = smax // n
     scale = d ** -0.5
@@ -79,23 +84,28 @@ def ring_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         start = jnp.zeros((b,), jnp.int32)   # pos >= 0 is vacuous
     cache_index = jnp.asarray(cache_index, jnp.int32)
 
-    kv_spec = P(None, axis_name, None, None)
+    stacked = layer is not None
+    layer = jnp.asarray(0 if layer is None else layer, jnp.int32)
+    kv_spec = P(*(None,) * (k_cache.ndim - 2), axis_name, None)
     rep4 = P(None, None, None, None)
 
-    def ringd(q_loc, k_loc, v_loc, idx0, start_loc):
+    def ringd(q_loc, k_loc, v_loc, idx0, start_loc, layer_loc):
+        li = layer_loc if stacked else None
+        k_loc = layer_of(k_loc, li).astype(q_loc.dtype)
+        v_loc = layer_of(v_loc, li).astype(q_loc.dtype)
         idx = jax.lax.axis_index(axis_name)
         pos = idx * s_loc + jnp.arange(s_loc)
         visible = (pos <= idx0)[None, :] & (pos[None, :] >= start_loc[:, None])
         if window > 0:
             visible = visible & (pos > idx0 - window)[None, :]
         q0 = q_loc[:, 0].reshape(b, kv, g, d)
-        sc = jnp.einsum("bkgd,btkd->bkgt", q0, k_loc
+        sc = jnp.einsum("bkgd,bktd->bkgt", q0, k_loc
                         ).astype(jnp.float32) * scale
         sc = jnp.where(visible[:, None, None, :], sc, NEG_INF)
         m = sc.max(axis=-1)                              # [B,KV,G]
         p = jnp.exp(sc - m[..., None])
         l = p.sum(axis=-1)
-        acc = jnp.einsum("bkgt,btkd->bkgd", p,
+        acc = jnp.einsum("bkgt,bktd->bkgd", p,
                          v_loc.astype(jnp.float32))
 
         def merge(a, b_):
@@ -119,8 +129,8 @@ def ring_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 
     return compat.shard_map(
         ringd, mesh,
-        in_specs=(rep4, kv_spec, kv_spec, P(), P(None)),
-        out_specs=rep4)(q, k_cache, v_cache, cache_index, start)
+        in_specs=(rep4, kv_spec, kv_spec, P(), P(None), P()),
+        out_specs=rep4)(q, k_cache, v_cache, cache_index, start, layer)
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -31,6 +31,8 @@ from typing import Any, Mapping, Optional, Sequence, Union
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.dist import compat
+
 # A rule value: no sharding, one mesh axis, or an ordered tuple of mesh axes.
 Physical = Union[None, str, tuple]
 
@@ -126,16 +128,19 @@ def serve_rules(long_context: bool = False) -> ShardingRules:
     TP rank ("heads_act"/"kv_heads_act" -> None).
 
     ``long_context`` switches the KV cache from head sharding to sequence
-    sharding ("cache_seq" -> model): the attend_decode softmax over the
-    sharded axis becomes a distributed log-sum-exp, so the multi-GB cache
-    never moves.
+    sharding ("cache_seq" -> model, "kv_heads" -> None): the attend_decode
+    softmax over the sharded axis becomes a distributed log-sum-exp, so the
+    multi-GB cache never moves.  The cache leaf names its heads before its
+    sequence, and the first logical axis takes the mesh axis, so the heads
+    must give it up; the K/V projections are then replicated, and each
+    device makes the new token that its sequence shard may hold.
     """
     return ShardingRules({
         "batch": ("pod", "data"),
         "seq": None,
         "embed": None,
         "heads": "model",
-        "kv_heads": "model",
+        "kv_heads": None if long_context else "model",
         "head_dim": None,
         "mlp": "model",
         "vocab": "model",
@@ -184,6 +189,19 @@ def active_mesh():
 def active_rules() -> Optional[ShardingRules]:
     stack = _stack()
     return stack[-1][1] if stack else None
+
+
+def per_shard(fn, x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:
+    """``fn`` applied to each device's shard of ``x`` under the active mesh,
+    ``x`` laid out by ``logical_axes`` as ``constrain`` lays it out; plain
+    ``fn(x)`` without a mesh.  For ops the SPMD partitioner cannot split,
+    such as a layout constraint: it would gather ``x`` whole for them."""
+    mesh = active_mesh()
+    rules = active_rules()
+    if mesh is None or rules is None:
+        return fn(x)
+    spec = rules.spec(logical_axes, shape=x.shape, mesh=mesh)
+    return compat.shard_map(fn, mesh, in_specs=(spec,), out_specs=spec)(x)
 
 
 def constrain(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:

@@ -9,6 +9,11 @@ Three execution paths share one set of projection weights:
     ``repro/kernels/flash_attention`` is the TPU hot-path variant.
   * ``attend_decode``  — one query position against a (possibly
     sequence-sharded) KV cache with masked online softmax.
+
+The decode cache holds K and V as ``[B, KV, Smax, D]``: each (slot, kv-head)
+pair is one ``[Smax, D]`` matrix, the operand the two decode dots batch over,
+so the cache is read without a relayout.  A layer stack keeps one such leaf
+per layer kind with a leading layer axis, ``[L, B, KV, Smax, D]``.
 """
 from __future__ import annotations
 
@@ -17,13 +22,17 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import ArchConfig
 from repro.dist.masking import (NEG_INF, PAD_SENTINEL as _PAD_SENTINEL,
                                 mask_bias as _mask_bias)
-from repro.dist.sharding import constrain
+from repro.dist.sharding import active_mesh, constrain, per_shard
 from repro.models.layers import rope
 from repro.models.module import ParamSpec
+
+# Logical axes of a decode cache leaf, [B, KV, Smax, D]
+KV_CACHE_AXES = ("batch", "kv_heads", "cache_seq", "head_dim")
 
 
 def attention_spec(cfg: ArchConfig, cross: bool = False) -> dict:
@@ -184,7 +193,7 @@ def attend_local(q, k, v, *, window: int, q_offset: int = 0) -> jax.Array:
 
 def attend_decode(q, k_cache, v_cache, cache_index, *, window: int = 0,
                   start=None) -> jax.Array:
-    """Single-position decode.  q:[B,1,H,D]; caches:[B,Smax,KV,D].
+    """Single-position decode.  q:[B,1,H,D]; caches:[B,KV,Smax,D].
 
     GQA is computed in *grouped* form (no KV expansion: the cache is the
     dominant HBM traffic at decode and must be read exactly once).  The
@@ -193,10 +202,9 @@ def attend_decode(q, k_cache, v_cache, cache_index, *, window: int = 0,
     reduces tiny [B,H] stats over the mesh instead of resharding the
     multi-GB cache (context-parallel decode)."""
     b, one, h, d = q.shape
-    kv = k_cache.shape[2]
+    kv, smax = k_cache.shape[1], k_cache.shape[2]
     g = h // kv
     scale = d ** -0.5
-    smax = k_cache.shape[1]
     pos = jnp.arange(smax)
     visible = (pos <= cache_index)[None, :]
     if window > 0:
@@ -207,11 +215,11 @@ def attend_decode(q, k_cache, v_cache, cache_index, *, window: int = 0,
         visible = visible & (pos[None, :] >= start[:, None])
     q = constrain(q, "batch", "seq", "heads_act", "head_dim")
     qg = q.reshape(b, one, kv, g, d)
-    s = jnp.einsum("bikgd,btkd->bkgit", qg, k_cache).astype(jnp.float32) * scale
+    s = jnp.einsum("bikgd,bktd->bkgit", qg, k_cache).astype(jnp.float32) * scale
     s = constrain(s, "batch", "kv_heads_act", None, "seq", "cache_seq")
     s = jnp.where(visible[:, None, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgit,btkd->bikgd", p.astype(q.dtype), v_cache)
+    out = jnp.einsum("bkgit,bktd->bikgd", p.astype(q.dtype), v_cache)
     out = out.reshape(b, one, h, d)
     return constrain(out, "batch", "seq", "heads_act", "head_dim")
 
@@ -226,7 +234,7 @@ def attention(cfg: ArchConfig, params: dict, x: jax.Array, *,
     """Full-sequence attention (train / prefill).  Cross-attn via kv_src.
 
     With ``return_kv`` also returns the post-rope (k, v) in cache layout
-    [B,S,KV,D] so prefill can populate the decode cache.  ``local_block``
+    [B,KV,S,D] so prefill can populate the decode cache.  ``local_block``
     switches windowed layers to the O(S*2w) banded path (§Perf)."""
     b, s, _ = x.shape
     q, k, v = _project_qkv(cfg, params, x, kv_src)
@@ -236,7 +244,7 @@ def attention(cfg: ArchConfig, params: dict, x: jax.Array, *,
         q = rope(q, positions, cfg.rope_theta)
         kv_pos = positions if kv_src is None else jnp.arange(k.shape[1])[None, :]
         k = rope(k, kv_pos, cfg.rope_theta)
-    kv = (k, v)
+    kv = (k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
     k = _expand_kv(k, cfg.n_heads)
     v = _expand_kv(v, cfg.n_heads)
     if local_block and window > 0 and causal and s > window:
@@ -263,12 +271,69 @@ def attention(cfg: ArchConfig, params: dict, x: jax.Array, *,
     return y
 
 
+def write_token(cache: jax.Array, new: jax.Array, cache_index,
+                layer=None) -> jax.Array:
+    """Write one position's K or V, ``new`` [B,1,KV,D], into ``cache`` at
+    ``cache_index``: a [B,KV,Smax,D] leaf, or layer ``layer`` of a stacked
+    [L,B,KV,Smax,D] one.  The update has sequence extent 1, so under a
+    donated cache (or a while-loop carry) the write is in place.
+
+    The written cache is pinned to the layout the device stores it in
+    (``stored_layout``): left free, XLA lays a scan's carry out like the
+    projection that makes the token ([B, D] minor), and then copies the
+    whole stack into that layout and back every step, and each layer out
+    again for the dots.  Under a mesh the pin is set on each device's
+    shard: the partitioner cannot split a layout constraint and would
+    gather the stack for it."""
+    upd = new.astype(cache.dtype).transpose(0, 2, 1, 3)       # [B,KV,1,D]
+    zero = jnp.zeros((), jnp.int32)
+    at = (zero, zero, jnp.asarray(cache_index, jnp.int32), zero)
+    if layer is None:
+        out = jax.lax.dynamic_update_slice(cache, upd, at)
+    else:
+        out = jax.lax.dynamic_update_slice(
+            cache, upd[None], (jnp.asarray(layer, jnp.int32),) + at)
+    return per_shard(lambda c: with_layout_constraint(c, stored_layout(c)),
+                     out, *("layers",) * (out.ndim - 4), *KV_CACHE_AXES)
+
+
+def layout_device():
+    """The device whose storage layouts a trace assumes: the active mesh's,
+    else the default device."""
+    mesh = active_mesh()
+    return mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+
+
+def stored_layout(x: jax.Array) -> Layout:
+    """The layout ``layout_device()`` stores an array of ``x``'s shape in,
+    which a donated argument arrives in and its result leaves in.  On a TPU
+    v5e a cache leaf [.., Smax, D] is row-major for D = 128 and 256 but
+    sequence-minor for D = 64."""
+    dev = layout_device()
+    stored = dev.client.get_default_layout(x.dtype, x.shape, dev)
+    return Layout(Layout.from_pjrt_layout(stored).major_to_minor)
+
+
+def layer_of(cache: jax.Array, layer=None) -> jax.Array:
+    """Layer ``layer`` of a stacked cache leaf (the leaf itself when
+    ``layer`` is None)."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+
+
 def attention_decode_step(cfg: ArchConfig, params: dict, x: jax.Array,
                           cache: dict, cache_index: jax.Array, *,
                           window: int = 0, use_rope: bool = True,
                           update_cache: bool = True, start=None,
-                          stream_kv: bool = False) -> tuple[jax.Array, dict]:
-    """One decode step.  x:[B,1,d]; cache: {"k","v"}: [B,Smax,KV,D].
+                          stream_kv: bool = False,
+                          layer=None) -> tuple[jax.Array, dict]:
+    """One decode step.  x:[B,1,d]; cache: {"k","v"}: [B,KV,Smax,D], or
+    with ``layer`` the stacked [L,B,KV,Smax,D] leaves of a layer scan, of
+    which this step writes and reads layer ``layer`` alone.  The new token
+    is written before the read, so the attention sees position
+    ``cache_index``; the returned cache is the written one (``cache``
+    itself without ``update_cache``: cross-attention reads only).
 
     ``stream_kv`` routes the cache read through the decode ring
     (``dist.ring_attention.ring_decode``): with ``serve_rules(
@@ -297,19 +362,15 @@ def attention_decode_step(cfg: ArchConfig, params: dict, x: jax.Array,
         q = rope(q, pos, cfg.rope_theta)
         k_new = rope(k_new, pos, cfg.rope_theta)
     if update_cache:
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k_new.astype(cache["k"].dtype), cache_index, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v_new.astype(cache["v"].dtype), cache_index, axis=1)
-    else:                       # cross-attention: cache prefilled, never grows
-        k_cache, v_cache = cache["k"], cache["v"]
+        cache = {"k": write_token(cache["k"], k_new, cache_index, layer),
+                 "v": write_token(cache["v"], v_new, cache_index, layer)}
     if stream_kv:
         from repro.dist.ring_attention import ring_decode
-        out = ring_decode(q, k_cache.astype(dtype), v_cache.astype(dtype),
-                          cache_index, window=window, start=start)
+        out = ring_decode(q, cache["k"], cache["v"], cache_index,
+                          window=window, start=start, layer=layer)
     else:
-        out = attend_decode(q, k_cache.astype(dtype), v_cache.astype(dtype),
+        out = attend_decode(q, layer_of(cache["k"], layer).astype(dtype),
+                            layer_of(cache["v"], layer).astype(dtype),
                             cache_index, window=window, start=start)
     y = jnp.einsum("bshd,hdk->bsk", out.astype(dtype), params["wo"].astype(dtype))
-    new_cache = {"k": k_cache, "v": v_cache} if update_cache else cache
-    return y, new_cache
+    return y, cache

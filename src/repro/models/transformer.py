@@ -21,6 +21,12 @@ from repro.models import xlstm as xlstm_mod
 from repro.models.layers import apply_norm, mlp, mlp_spec, norm_spec
 from repro.models.module import ParamSpec, stack_tree
 
+# Positional KV leaves of a block's decode cache, [B, KV, Smax, D] (the
+# encoder's length for the cross-attention pair).  Under the layer scan they
+# stay stacked: a step writes one token into its layer and reads that layer
+# in place.  Every other leaf is recurrent state, replaced whole each step.
+KV_LEAVES = frozenset({"k", "v", "xk", "xv"})
+
 # ---------------------------------------------------------------------------
 # Per-block param specs
 # ---------------------------------------------------------------------------
@@ -71,20 +77,18 @@ def block_cache_spec(cfg: ArchConfig, kind: str, batch: int, max_seq: int,
     if kind == "slstm":
         leaf = ParamSpec((batch, d), jnp.float32, ("batch", "embed"), init="zeros")
         return {"c": leaf, "n": leaf, "m": leaf, "h": leaf}
-    # attention KV cache; 'local' blocks only need the window (ring buffer)
-    seq = max_seq
-    cache = {"k": ParamSpec((batch, seq, kv, hd), cache_dtype,
-                            ("batch", "cache_seq", "kv_heads", "head_dim"), init="zeros"),
-             "v": ParamSpec((batch, seq, kv, hd), cache_dtype,
-                            ("batch", "cache_seq", "kv_heads", "head_dim"), init="zeros")}
+    # attention KV cache, one [max_seq, head_dim] matrix per (slot, kv head)
+    kv_leaf = ParamSpec((batch, kv, max_seq, hd), cache_dtype,
+                        attn.KV_CACHE_AXES, init="zeros")
+    cache = {"k": kv_leaf, "v": kv_leaf}
     if kind == "hybrid":
         cache["h_ssm"] = ParamSpec((batch, d, cfg.ssm_state), jnp.float32,
                                    ("batch", "mlp", None), init="zeros")
     if cross_len:
-        cache["xk"] = ParamSpec((batch, cross_len, kv, hd), cache_dtype,
-                                ("batch", None, "kv_heads", "head_dim"), init="zeros")
-        cache["xv"] = ParamSpec((batch, cross_len, kv, hd), cache_dtype,
-                                ("batch", None, "kv_heads", "head_dim"), init="zeros")
+        cross_leaf = ParamSpec((batch, kv, cross_len, hd), cache_dtype,
+                               ("batch", "kv_heads", None, "head_dim"), init="zeros")
+        cache["xk"] = cross_leaf
+        cache["xv"] = cross_leaf
     return cache
 
 
@@ -143,7 +147,7 @@ def block_prefill(cfg: ArchConfig, kind: str, params: dict, x: jax.Array, *,
 
     def pad_seq(a):
         return jnp.pad(a.astype(cache_dtype),
-                       ((0, 0), (0, max_seq - s), (0, 0), (0, 0)))
+                       ((0, 0), (0, 0), (0, max_seq - s), (0, 0)))
 
     if kind == "mlstm":
         y, (C, n, m) = xlstm_mod.mlstm_apply(cfg, params, x)
@@ -186,7 +190,10 @@ def block_prefill(cfg: ArchConfig, kind: str, params: dict, x: jax.Array, *,
 
 def block_decode(cfg: ArchConfig, kind: str, params: dict, x: jax.Array,
                  cache: dict, cache_index: jax.Array, start=None,
-                 stream_kv: bool = False) -> tuple[jax.Array, dict]:
+                 stream_kv: bool = False, layer=None) -> tuple[jax.Array, dict]:
+    """One block's decode step.  With ``layer`` the ``KV_LEAVES`` of
+    ``cache`` are the stacked leaves of the layer scan and the block touches
+    layer ``layer`` of them alone; its other leaves are this layer's."""
     use_rope = cfg.positional == "rope"
     if kind == "mlstm":
         st = (cache["C"], cache["n"], cache["m"])
@@ -202,7 +209,8 @@ def block_decode(cfg: ArchConfig, kind: str, params: dict, x: jax.Array,
     kv_cache = {"k": cache["k"], "v": cache["v"]}
     a, kv_cache = attn.attention_decode_step(
         cfg, params["attn"], h, kv_cache, cache_index,
-        window=window, use_rope=use_rope, start=start, stream_kv=stream_kv)
+        window=window, use_rope=use_rope, start=start, stream_kv=stream_kv,
+        layer=layer)
     new_cache = dict(cache)
     new_cache.update(kv_cache)
     if kind == "hybrid":
@@ -216,10 +224,10 @@ def block_decode(cfg: ArchConfig, kind: str, params: dict, x: jax.Array,
     if "xk" in cache and "cross" in params:
         hx = apply_norm(cfg.norm_kind, params["norm_x"], x, impl=cfg.norm_impl)
         xc = {"k": cache["xk"], "v": cache["xv"]}
-        enc_len = cache["xk"].shape[1]
+        enc_len = cache["xk"].shape[-2]
         cx, _ = attn.attention_decode_step(
             cfg, params["cross"], hx, xc, jnp.int32(enc_len - 1),
-            use_rope=False, update_cache=False)
+            use_rope=False, update_cache=False, layer=layer)
         x = x + cx
     if kind == "moe":
         h2 = apply_norm(cfg.norm_kind, params["norm2"], x, impl=cfg.norm_impl)
@@ -351,31 +359,37 @@ def stack_decode(cfg: ArchConfig, params: dict, x: jax.Array, cache: dict,
                  stream_kv: bool = False) -> tuple[jax.Array, dict]:
     """Decode through the layer stack.
 
-    The stacked cache rides in the scan CARRY and is updated in place with
-    dynamic_update_slice — while-loop carries alias reliably, so per-step
-    HBM traffic is one token-slice write per layer, not a rewrite of the
-    multi-GB cache (which is what scanning the cache through xs/ys costs).
+    The stacked cache rides in the scan CARRY (while-loop carries alias, so
+    a donated cache is updated in place).  Its ``KV_LEAVES`` go to the block
+    whole, with the layer index: each layer writes its one new token into
+    its layer of the stack and the attention reads that layer where it
+    lies, so per-step cache traffic is the read the attention needs plus one
+    token written per layer.  Recurrent leaves are sliced out per layer and
+    written back whole.
     """
     scan_params = params.get("scan")
     new_cache: dict[str, Any] = {"tail": {}}
 
     def period_body(carry, period_params):
         x, cache_st, li = carry
+        cache_st = dict(cache_st)
         for i, kind in enumerate(cfg.layer_pattern):
             key = f"p{i}"
             if key not in period_params:
                 continue
-            layer_cache = jax.tree.map(
-                lambda c: jax.lax.dynamic_index_in_dim(c, li, 0, keepdims=False),
-                cache_st[key])
+            stacked = cache_st[key]
+            layer_cache = {
+                n: c if n in KV_LEAVES
+                else jax.lax.dynamic_index_in_dim(c, li, 0, keepdims=False)
+                for n, c in stacked.items()}
             x, c_new = block_decode(cfg, kind, period_params[key], x,
                                     layer_cache, cache_index, start=start,
-                                    stream_kv=stream_kv)
-            cache_st = dict(cache_st)
-            cache_st[key] = jax.tree.map(
-                lambda st, cn: jax.lax.dynamic_update_index_in_dim(
-                    st, cn.astype(st.dtype), li, 0),
-                cache_st[key], c_new)
+                                    stream_kv=stream_kv, layer=li)
+            cache_st[key] = {
+                n: c_new[n] if n in KV_LEAVES
+                else jax.lax.dynamic_update_index_in_dim(
+                    c, c_new[n].astype(c.dtype), li, 0)
+                for n, c in stacked.items()}
         return (x, cache_st, li + 1), None
 
     if scan_params:

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.registry import Model
+from repro.models.transformer import KV_LEAVES
 from repro.obs.telemetry import trace_span, trace_step
 # Back-compat re-exports: the admission cost model moved to serve.policy
 # when the decode_step pseudo-kernel split into prefill_step/decode_step.
@@ -45,9 +46,6 @@ from repro.serve.policy import (  # noqa: F401
     PREFILL_STEP_FEATURES, PREFILL_STEP_KERNEL, cost_model_from_cache,
     record_request_time, split_cost_model_from_cache)
 
-# cache leaves that are positional KV state (masked via start, never
-# reset); everything else is recurrent state and is zeroed on admission
-_KV_LEAVES = frozenset({"k", "v", "xk", "xv"})
 _RECURRENT_KINDS = frozenset({"mlstm", "slstm", "hybrid"})
 _SUPPORTED_KINDS = frozenset({"attn", "local", "moe"}) | _RECURRENT_KINDS
 
@@ -97,7 +95,7 @@ def _zero_slot(tree: dict, slot, axis: int) -> dict:
     for name, leaf in tree.items():
         if isinstance(leaf, dict):
             out[name] = _zero_slot(leaf, slot, axis)
-        elif name in _KV_LEAVES:
+        elif name in KV_LEAVES:      # positional: masked via start, kept
             out[name] = leaf
         else:
             row = jnp.zeros(leaf.shape[:axis] + leaf.shape[axis + 1:],

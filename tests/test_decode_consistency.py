@@ -61,3 +61,50 @@ def test_prefill_then_decode_matches_forward():
     np.testing.assert_allclose(np.asarray(dec),
                                np.asarray(logits_fwd[:, pre:]),
                                rtol=2e-3, atol=2e-3)
+
+
+# stacks with scanned periods and unscanned tail layers: (arch, n_layers)
+STACKS = {
+    "local+tail": ("gemma3-1b", 8),          # 5 local + attn, then 2 local
+    "moe+tail": ("llama4-maverick-400b-a17b", 3),     # attn, moe, then attn
+    "hybrid": ("hymba-1.5b", 3),
+    "recurrent+tail": ("xlstm-1.3b", 10),    # 7 mlstm + slstm, then 2 mlstm
+    "cross": ("whisper-medium", 3),          # read-only encoder K/V
+}
+
+
+@pytest.mark.parametrize("stack", sorted(STACKS))
+def test_prefill_then_decode_matches_forward_stacks(stack):
+    """Prefill writes the cache in its decode layout for scanned and tail
+    layers alike; decode then writes each token into its layer in place."""
+    arch, n_layers = STACKS[stack]
+    cfg = dataclasses.replace(ARCHS[arch].reduced(), n_layers=n_layers,
+                              compute_dtype="float32", capacity_factor=8.0)
+    model = build_model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    B, S = 2, 12
+    toks = jnp.asarray(np.random.RandomState(2).randint(
+        1, cfg.vocab_size, (B, S)), jnp.int32)
+    extra = {}
+    if cfg.frontend == "frame":
+        extra["frames"] = jnp.asarray(np.random.RandomState(3).randn(
+            B, cfg.n_frontend_tokens, cfg.d_model) * 0.05, jnp.float32)
+    logits_fwd, _ = model.forward(
+        params, {"tokens": toks, "labels": toks, **extra}, remat=False)
+    pre = S - 5
+    logits_pre, cache = model.prefill(
+        params, {"tokens": toks[:, :pre], **extra}, max_seq=S,
+        cache_dtype=jnp.float32)
+    assert jax.tree.structure(cache) == jax.tree.structure(
+        model.init_cache(B, S, cache_dtype=jnp.float32))
+    np.testing.assert_allclose(np.asarray(logits_pre),
+                               np.asarray(logits_fwd[:, :pre]),
+                               rtol=2e-3, atol=2e-3)
+    step = jax.jit(model.decode_step, donate_argnums=(1,))
+    outs = []
+    for t in range(pre, S):
+        lg, cache = step(params, cache, toks[:, t:t + 1], jnp.int32(t))
+        outs.append(lg)
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(outs, 1)),
+                               np.asarray(logits_fwd[:, pre:]),
+                               rtol=2e-3, atol=2e-3)

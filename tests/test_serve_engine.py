@@ -511,17 +511,25 @@ RING_DECODE_SCRIPT = textwrap.dedent("""
     rng = np.random.RandomState(0)
     b, h, kv, d, smax = 2, 4, 2, 16, 32
     q = jnp.asarray(rng.randn(b, 1, h, d) * 0.4, jnp.float32)
-    k = jnp.asarray(rng.randn(b, smax, kv, d) * 0.4, jnp.float32)
-    v = jnp.asarray(rng.randn(b, smax, kv, d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, kv, smax, d) * 0.4, jnp.float32)
+    v = jnp.asarray(rng.randn(b, kv, smax, d), jnp.float32)
+    # a layer stack whose layer 1 is (k, v): read inside the SPMD body
+    k_stack = jnp.stack([v, k, -k])
+    v_stack = jnp.stack([k, v, -v])
     for idx in (3, 7, 12, 31):          # shard-interior + boundary indices
         for window in (0, 8):
             for start in (None, jnp.asarray([0, 5], jnp.int32)):
-                out = ring_decode(q, k, v, jnp.int32(idx), mesh=mesh,
-                                  window=window, start=start)
                 ref = attend_decode(q, k, v, jnp.int32(idx),
                                     window=window, start=start)
+                out = ring_decode(q, k, v, jnp.int32(idx), mesh=mesh,
+                                  window=window, start=start)
                 err = float(jnp.max(jnp.abs(out - ref)))
                 assert err <= 2e-5, (idx, window, start is None, err)
+                out = ring_decode(q, k_stack, v_stack, jnp.int32(idx),
+                                  mesh=mesh, window=window, start=start,
+                                  layer=jnp.int32(1))
+                err = float(jnp.max(jnp.abs(out - ref)))
+                assert err <= 2e-5, ("stacked", idx, window, err)
     print("RING_DECODE_OK")
 """)
 

@@ -7,12 +7,10 @@ refuses, and VMEM overflows.  Nothing runs, so nothing here says anything
 about results or times.
 """
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
 import repro.kernels
 from repro.kernels.blur import ops as blur_ops
@@ -24,33 +22,8 @@ from repro.kernels.maxpool import ops as maxpool_ops
 PALLAS_KERNELS = ("matmul", "matvec", "conv2d", "maxpool")
 
 
-@pytest.fixture(scope="module")
-def topo():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture(scope="module", autouse=True)
-def no_compile_cache():
-    """A compile for a described chip is written to the persistent cache but
-    cannot be read back without one; keep these compiles out of it."""
-    from jax.experimental.compilation_cache import compilation_cache
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+# the described chip: ``topo`` and ``one_chip`` (tests/conftest.py)
+pytestmark = pytest.mark.usefixtures("no_compile_cache")
 
 
 @pytest.fixture(scope="module")

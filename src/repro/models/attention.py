@@ -293,8 +293,28 @@ def write_token(cache: jax.Array, new: jax.Array, cache_index,
     else:
         out = jax.lax.dynamic_update_slice(
             cache, upd[None], (jnp.asarray(layer, jnp.int32),) + at)
+    return _pin_stored(out)
+
+
+def write_rows(cache: jax.Array, new: jax.Array, slot, row) -> jax.Array:
+    """Write one request's K or V, ``new`` [1,KV,P,D], into batch slot
+    ``slot`` of ``cache`` at rows [row, row + P): a [B,KV,Smax,D] leaf, or
+    every layer of a stacked [L,B,KV,Smax,D] one (``new`` then [L,1,KV,P,D]).
+    One update per leaf, in place under a donated cache, pinned to the
+    stored layout as ``write_token`` pins it.  ``dynamic_update_slice``
+    clamps a block that would run past ``Smax``: callers keep
+    ``row + P <= Smax``."""
+    zero = jnp.zeros((), jnp.int32)
+    at = (zero,) * (cache.ndim - 4) + (
+        jnp.asarray(slot, jnp.int32), zero, jnp.asarray(row, jnp.int32), zero)
+    return _pin_stored(jax.lax.dynamic_update_slice(
+        cache, new.astype(cache.dtype), at))
+
+
+def _pin_stored(cache: jax.Array) -> jax.Array:
+    """``cache`` pinned to ``stored_layout``, on each device's shard."""
     return per_shard(lambda c: with_layout_constraint(c, stored_layout(c)),
-                     out, *("layers",) * (out.ndim - 4), *KV_CACHE_AXES)
+                     cache, *("layers",) * (cache.ndim - 4), *KV_CACHE_AXES)
 
 
 def layout_device():

@@ -155,6 +155,30 @@ class Model:
         logits = unembed(params.get("unembed", params["embed"]), x)
         return logits, cache
 
+    def prefill_into(self, params: dict, cache: dict, tokens: jax.Array,
+                     slot, row, length) -> tuple[jax.Array, dict]:
+        """Prefill one request into slot ``slot`` of a batched decode cache.
+
+        ``tokens`` [1, Pb] is the prompt right-padded to Pb, of which the
+        first ``length`` are real.  Its post-rope K/V, at request-local
+        positions 0..Pb-1, go to rows [row, row + Pb) of the slot in every
+        layer: what ``decode_step`` with ``start = row`` would have written
+        token by token.  Rows from ``row + length`` on hold the padding's
+        K/V, which the slot's own decode steps overwrite before reading.
+        Only the hidden state at ``length - 1`` is unembedded.  Returns
+        (logits [1, V], cache)."""
+        cfg = self.cfg
+        dtype = jnp.dtype(cfg.compute_dtype)
+        x = embed(params["embed"], tokens, dtype)
+        x, new = tfm.stack_prefill(cfg, params["stack"], x,
+                                   max_seq=tokens.shape[1],
+                                   cache_dtype=jax.tree.leaves(cache)[0].dtype)
+        cache = tfm.stack_write_rows(cache, new, slot, row)
+        x = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+        x = apply_norm(cfg.norm_kind, params["final_norm"], x, impl=cfg.norm_impl)
+        logits = unembed(params.get("unembed", params["embed"]), x)
+        return logits[:, 0], cache
+
     # -- single-token decode -------------------------------------------------
     def decode_step(self, params: dict, cache: dict, tokens: jax.Array,
                     cache_index: jax.Array, start=None,

@@ -354,6 +354,24 @@ def stack_prefill(cfg: ArchConfig, params: dict, x: jax.Array, *,
     return x, cache
 
 
+def stack_write_rows(cache: dict, new: dict, slot, row) -> dict:
+    """Write a one-sequence prefill's K/V (``stack_prefill``'s cache, batch
+    1) into batch slot ``slot`` of a batched decode cache at rows
+    [row, row + P), every layer of each leaf in one update.  Only
+    ``"k"``/``"v"`` leaves are written: the caller's stack holds nothing
+    else (full-attention blocks alone)."""
+    def put(blocks, fresh):
+        return {key: {n: attn.write_rows(c, fresh[key][n], slot, row)
+                      if n in ("k", "v") else c
+                      for n, c in block.items()}
+                for key, block in blocks.items()}
+
+    out = {"tail": put(cache["tail"], new["tail"])}
+    if "scan" in cache:
+        out["scan"] = put(cache["scan"], new["scan"])
+    return out
+
+
 def stack_decode(cfg: ArchConfig, params: dict, x: jax.Array, cache: dict,
                  cache_index: jax.Array, start=None,
                  stream_kv: bool = False) -> tuple[jax.Array, dict]:

@@ -35,7 +35,8 @@ on CI artifacts long after the run.  ``waterfalls_from_telemetry``
 renders the serve-engine side: per-request TTFT decomposed into queue
 wait / prefill execution / decode execution / scheduling overhead from
 the ``request.arrival:<rid>`` / ``admission:<rid>`` / per-step
-``serve.step`` spans ``serve.engine`` records.
+``serve.step`` and per-request ``serve.prefill`` spans ``serve.engine``
+records.
 
 CLI: ``python -m repro.obs explain <trace.json|telemetry.json> ...``.
 """
@@ -447,11 +448,12 @@ def waterfalls_from_telemetry(doc: dict) -> dict:
     """Per-request TTFT waterfalls from a telemetry document recorded by
     ``serve.ServeEngine``: for each request with an arrival and a first
     token, TTFT decomposes into queue wait (arrival -> admission),
-    prefill/decode execution (the request's share of ``serve.step`` spans
-    inside [admission, first token], split by the per-slot phase each
-    span recorded), and scheduling overhead (the window not covered by
-    any step the request was active in).  ``residual_s`` is whatever the
-    decomposition failed to attribute — the < 5% honesty check."""
+    prefill/decode execution (the request's share of ``serve.step`` and
+    ``serve.prefill`` spans inside [admission, first token], split by the
+    per-slot phase each span recorded), and scheduling overhead (the window
+    not covered by any step the request was active in).  ``residual_s`` is
+    whatever the decomposition failed to attribute — the < 5% honesty
+    check."""
     epoch = float(doc.get("epoch", 0.0))
     arrival: dict = {}
     admit: dict = {}
@@ -461,7 +463,7 @@ def waterfalls_from_telemetry(doc: dict) -> dict:
     steps = []
     for e in doc.get("events", ()):
         name, cat = e.get("name", ""), e.get("cat")
-        if cat == "serve.step":
+        if cat in ("serve.step", "serve.prefill"):
             steps.append(e)
             continue
         rid = _rid_of(e)

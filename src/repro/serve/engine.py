@@ -25,7 +25,8 @@ counters):
 - gauges ``serve.queue_depth`` (on submit/admit) and
   ``serve.goodput_tok_s`` (end of ``run_trace``);
 - counters ``serve.requests_completed``, ``serve.tokens_generated``,
-  ``serve.requests_rejected``, ``serve.admission_fallback``;
+  ``serve.requests_rejected``, ``serve.admission_fallback``,
+  ``serve.prefills`` (whole-prompt prefills);
 - ``admission:<rid>`` instants (policy, predicted seconds, queue wait);
 - per-request ``serve.request`` residuals (predicted vs actual service
   time) feeding the existing ``DriftMonitor``;
@@ -33,8 +34,9 @@ counters):
   ``request.arrival:<rid>`` / ``first_token:<rid>`` /
   ``request.done:<rid>`` instants plus one ``serve.step`` span per engine
   iteration whose args list the (rid, slot, phase) of every active
-  request — enough for ``explain`` to rebuild a TTFT waterfall (queue
-  wait / prefill / decode / scheduling overhead) per request.
+  request, and one ``serve.prefill`` span per whole-prompt prefill —
+  enough for ``explain`` to rebuild a TTFT waterfall (queue wait /
+  prefill / decode / scheduling overhead) per request.
 
 Without a ``Telemetry`` (or with ``NULL_TELEMETRY``) the step path makes
 no telemetry call and the compiled step gets none.  The step's spans on
@@ -221,6 +223,16 @@ class ServeEngine(ContinuousBatcher):
                 cat="serve.step", step=self.steps, requests=self._step_reqs)
         return out
 
+    def _prefill(self, req, slot: int) -> None:
+        t0 = time.perf_counter()
+        super()._prefill(req, slot)
+        if self.telemetry.enabled:
+            self.telemetry.count("serve.prefills")
+            self.telemetry.event(
+                f"engine.prefill:{req.rid}", t0, time.perf_counter(),
+                cat="serve.prefill",
+                requests=[{"rid": req.rid, "slot": slot, "phase": "prefill"}])
+
     # -- queue + lifecycle hooks ---------------------------------------------
     def submit(self, req) -> bool:
         tel = self.telemetry
@@ -354,6 +366,8 @@ class ServeEngine(ContinuousBatcher):
                "rejected": len(self.rejected),
                "tokens_generated": sum(len(r.generated)
                                        for r in self.completed),
+               "prefills": self.prefills,
+               "prefill_tokens": self.prefill_tokens,
                "policy": self.policy_name,
                "admission_fallback": self.policy_name
                != self.requested_policy}

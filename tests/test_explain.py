@@ -287,11 +287,14 @@ def test_serve_waterfalls_decompose_ttft(tmp_path):
         assert row["prefill_s"] > 0      # every request consumed a prompt
         assert row["ttft_s"] > 0 and row["total_s"] >= row["ttft_s"]
         assert row["tokens"] == 3 + rid
-    # the per-step spans carry (rid, slot, phase) for every active slot
+    # the per-step spans carry (rid, slot, phase) for every active slot;
+    # each prompt went in with a prefill span of its own
     steps = tel.events(cat="serve.step")
     assert len(steps) == stats["engine_steps"]
-    assert any(x["phase"] == "prefill"
-               for e in steps for x in e["args"]["requests"])
+    prefills = [x for e in tel.events(cat="serve.prefill")
+                for x in e["args"]["requests"]]
+    assert sorted(x["rid"] for x in prefills) == [0, 1, 2, 3]
+    assert all(x["phase"] == "prefill" for x in prefills)
     assert any(x["phase"] == "decode"
                for e in steps for x in e["args"]["requests"])
 

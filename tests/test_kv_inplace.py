@@ -166,6 +166,52 @@ def test_compiled_decode_copies_no_layer_at_head_dim_64(case, one_chip,
     _assert_copies_no_layer(case, one_chip, n_heads=16, head_dim=64)
 
 
+# -- whole-prompt prefill into one slot ---------------------------------------
+
+def _prefill(model):
+    def prefill(params, cache, tokens, slot, row, length):
+        return model.prefill_into(params, cache, tokens, slot, row, length)
+    return prefill
+
+
+def _prefill_args(model, bucket, sharding=None):
+    params, cache, *_ = _step_args(model, sharding)
+    ints = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=sharding)
+    return params, cache, ints((1, bucket)), ints(()), ints(()), ints(())
+
+
+@pytest.mark.parametrize("bucket", [32, 1024])
+def test_prefill_writes_one_slot_per_kv_leaf(bucket):
+    """``Model.prefill_into`` writes each K/V leaf once, every layer of it,
+    one slot and the bucket's rows."""
+    cfg, model = _model("attn")
+    written, _ = _kv_shapes(model.cache_specs(B, S))
+    jaxpr = jax.make_jaxpr(_prefill(model))(
+        *_prefill_args(model, bucket)).jaxpr
+    updates = [tuple(e.invars[1].aval.shape) for e in _eqns(jaxpr)
+               if e.primitive.name == "dynamic_update_slice"
+               and tuple(e.invars[0].aval.shape) in written]
+    layers = cfg.n_layers
+    assert updates == [(layers, 1, cfg.n_kv_heads, bucket,
+                        cfg.resolved_head_dim)] * 2, updates
+
+
+@pytest.mark.parametrize("head_dim", [128, 64])
+def test_compiled_prefill_copies_no_layer(head_dim, one_chip, chip_layouts):
+    """Compiled for the chip with the cache donated, a 1024-token prefill
+    into the chat deployment's cache needs less scratch than one layer's K
+    and V: it writes its rows where the cache lies, in the layout the
+    decode step keeps it in (sequence-minor at heads of 64)."""
+    cfg, model = _model("attn", n_heads=128 // head_dim * 8,
+                        head_dim=head_dim)
+    layer_kv = 2 * B * cfg.n_kv_heads * S * cfg.resolved_head_dim * 2
+    compiled = jax.jit(_prefill(model), donate_argnums=(1,)).lower(
+        *_prefill_args(model, 1024, one_chip)).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_kv, (temp, layer_kv)
+
+
 # -- four chips -------------------------------------------------------------
 
 @pytest.fixture(scope="module")

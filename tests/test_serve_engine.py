@@ -33,6 +33,7 @@ from repro.serve import (ColdCacheError, ContinuousBatcher, ServeEngine,
 from repro.serve.policy import (DECODE_STEP_KERNEL, PREFILL_STEP_KERNEL,
                                 sjf_order)
 from repro.serve.request import ServeRequest
+from test_continuous_batching import _one_token_alone
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -316,17 +317,20 @@ def test_recurrent_slot_reset_matches_fresh_engine(tmp_path):
 
 def test_stream_kv_single_device_matches_dense(tmp_path, tiny_model):
     """``stream_kv=True`` without a >1-device mesh degenerates to the
-    dense decode path — outputs must be identical token-for-token."""
+    dense decode path — outputs must be identical token-for-token.  A
+    dense engine now takes the whole-prompt path, so the dense side is the
+    dense decode step fed one token at a time, as the stream_kv engine
+    feeds it."""
     model, params = tiny_model
-    outs = []
-    for stream_kv in (False, True):
-        reqs = poisson_trace(4, seed=6)
-        eng = ServeEngine(model, TuningCache(root=str(tmp_path / "tc")),
-                          params=params, max_slots=2, max_seq=64,
-                          admission="fifo", stream_kv=stream_kv)
-        eng.run_trace(reqs)
-        outs.append([r.generated for r in reqs])
-    assert outs[0] == outs[1]
+    reqs = poisson_trace(4, seed=6)
+    eng = ServeEngine(model, TuningCache(root=str(tmp_path / "tc")),
+                      params=params, max_slots=2, max_seq=64,
+                      admission="fifo", stream_kv=True)
+    eng.run_trace(reqs)
+    assert eng.stats()["prefills"] == 0
+    for r in reqs:
+        assert r.generated == _one_token_alone(
+            model, params, r.prompt, r.max_new, 64, stream_kv=False), r.rid
 
 
 def test_bounded_queue_rejects_overflow(tmp_path, tiny_model):
@@ -589,6 +593,37 @@ def test_step_spans_on_the_profiler_trace(tmp_path, tiny_model):
         assert worker != step.thread
         assert all(s.thread == worker for s in inside
                    if s.name.startswith("dispatch."))
+
+
+def test_dense_engine_prefills_each_request_inside_admit(tmp_path,
+                                                        tiny_model):
+    """A dense engine puts each admitted prompt in with one prefill: one
+    per request in its stats and telemetry, and on a profiled step a
+    ``serve.prefill`` span inside that step's ``serve.admit``."""
+    from _trace import host_spans, profiled
+
+    model, params = tiny_model
+    tel = Telemetry()
+    eng = ServeEngine(model, TuningCache(root=str(tmp_path / "tc")),
+                      params=params, max_slots=2, max_seq=64,
+                      admission="fifo", telemetry=tel, record_rows=False)
+    assert eng.whole_prompt
+    eng.submit(ServeRequest(rid=0, prompt=[3, 4], max_new=8))
+    eng.step()
+    eng.submit(ServeRequest(rid=1, prompt=[5] * 20, max_new=3))
+    with profiled(tmp_path / "trace"):
+        assert eng.step()
+    spans = host_spans(tmp_path / "trace", ("serve.",))
+    (step,) = [s for s in spans if s.name == "serve.step"]
+    (admit,) = [s for s in spans if s.name == "serve.admit"]
+    (prefill,) = [s for s in spans if s.name == "serve.prefill"]
+    assert step.holds(admit) and admit.holds(prefill)
+    while eng.step():
+        pass
+    stats = eng.stats()
+    assert stats["completed"] == 2
+    assert stats["prefills"] == 2 and stats["prefill_tokens"] == 22
+    assert tel.counters()["serve.prefills"] == 2
 
 
 def test_engine_without_telemetry_makes_no_telemetry_call(
